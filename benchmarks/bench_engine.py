@@ -6,9 +6,11 @@ pays one jit dispatch per client per SGD step (tau * K dispatches per
 round); the cohort backend stacks the cohort into one compiled
 vmap+scan call; the *sharded* cohort lays the client axis out over the
 local device mesh (``FLConfig.trainer_mesh_devices``) so the one call
-runs data-parallel across devices.  The sharded comparison spawns
-subprocesses because the forced host-device count must be set before
-jax initialises.  Writes ``BENCH_engine.json`` next to the repo root.
+runs data-parallel across devices.  Every timed leg runs in its own
+worker process and the parent never imports jax: the forced host-device
+count must be set before jax initialises, and on an accelerator host a
+parent that held the device would lock its workers out.  Writes
+``BENCH_engine.json`` next to the repo root.
 
 Usage:  PYTHONPATH=src python benchmarks/bench_engine.py [--fast|--smoke]
 
@@ -31,6 +33,7 @@ sys.path.insert(0, str(Path(__file__).resolve().parents[1] / "src"))
 
 
 def bench(scheme: str, trainer: str, rounds: int, warmup: int) -> dict:
+    """Timed CNN rounds for one (scheme, trainer) pair (worker body)."""
     from repro.fl import FLConfig, build_image_setup, build_runner
 
     model, px, py, test = build_image_setup(num_clients=10, seed=0)
@@ -93,16 +96,8 @@ def bench_sharded_cohort(task: str, clients: int, rounds: int, warmup: int,
     times = {1: [], devices: []}
     for _ in range(max(repeats, 1)):
         for ndev in (1, devices):
-            env = {**os.environ, "XLA_FLAGS":
-                   f"--xla_force_host_platform_device_count={ndev}"}
-            cmd = [sys.executable, __file__, "--_cohort-worker",
-                   "--task", task, "--clients", str(clients),
-                   "--rounds", str(rounds), "--warmup", str(warmup)]
-            r = subprocess.run(cmd, env=env, capture_output=True, text=True)
-            if r.returncode != 0:
-                raise RuntimeError(f"cohort worker ({ndev} devices) failed:"
-                                   f"\n{r.stderr[-2000:]}")
-            res = json.loads(r.stdout.strip().splitlines()[-1])
+            res = _run_cohort_worker(task, clients, rounds, warmup,
+                                     devices=ndev)
             assert res["devices"] == ndev, res
             times[ndev].append(res["per_round_s"])
     import statistics
@@ -118,22 +113,32 @@ def bench_sharded_cohort(task: str, clients: int, rounds: int, warmup: int,
     return out
 
 
-def _run_cohort_worker(task: str, clients: int, rounds: int, warmup: int,
-                       script: str | None = None) -> dict:
-    """One 1-device cohort-round measurement in a fresh process (the
-    protocol every stored per-round baseline in BENCH_engine.json uses).
-    ``script`` points at another checkout's bench_engine.py to time a
-    different revision (the worker is self-contained: it inserts its own
-    repo's ``src`` on sys.path)."""
+def _run_worker(argv: list, devices: int = 1,
+                script: str | None = None) -> dict:
+    """Run one timed leg in a fresh process and return its JSON result.
+
+    The worker is the only process that initialises jax, with
+    ``devices`` forced host devices.  ``script`` points at another
+    checkout's bench_engine.py to time a different revision (the worker
+    is self-contained: it inserts its own repo's ``src`` on sys.path).
+    """
     env = {**os.environ,
-           "XLA_FLAGS": "--xla_force_host_platform_device_count=1"}
-    cmd = [sys.executable, script or __file__, "--_cohort-worker",
-           "--task", task, "--clients", str(clients),
-           "--rounds", str(rounds), "--warmup", str(warmup)]
+           "XLA_FLAGS": f"--xla_force_host_platform_device_count={devices}"}
+    cmd = [sys.executable, script or __file__, "--_worker", *argv]
     r = subprocess.run(cmd, env=env, capture_output=True, text=True)
     if r.returncode != 0:
-        raise RuntimeError(f"cohort worker failed:\n{r.stderr[-2000:]}")
+        raise RuntimeError(f"worker {argv} ({devices} devices) failed:\n"
+                           f"{r.stderr[-2000:]}")
     return json.loads(r.stdout.strip().splitlines()[-1])
+
+
+def _run_cohort_worker(task: str, clients: int, rounds: int, warmup: int,
+                       script: str | None = None, devices: int = 1) -> dict:
+    """One cohort-round measurement in a fresh process (the protocol
+    every stored per-round baseline in BENCH_engine.json uses)."""
+    return _run_worker(["cohort", "--task", task, "--clients", str(clients),
+                        "--rounds", str(rounds), "--warmup", str(warmup)],
+                       devices=devices, script=script)
 
 
 def bench_telemetry_overhead(path: Path, quick: bool, clients: int,
@@ -176,8 +181,9 @@ def bench_telemetry_overhead(path: Path, quick: bool, clients: int,
             if base_script:  # interleave A/B within the session
                 theirs.append(_run_cohort_worker(
                     task, clients, rounds, 2, base_script)["per_round_s"])
-            ours.append(_run_cohort_worker(task, clients, rounds, 2)
-                        ["per_round_s"])
+            res = _run_cohort_worker(task, clients, rounds, 2)
+            ours.append(res["per_round_s"])
+            data["provenance"] = res["provenance"]
         per_round = statistics.median(ours)
         cell = {"per_round_s": per_round, "clients": clients, "tau": 10,
                 "rounds": rounds, "repeats": repeats,
@@ -212,9 +218,6 @@ def bench_telemetry_overhead(path: Path, quick: bool, clients: int,
                       "   (no stored baseline)")
         entry[task] = cell
     data["telemetry_overhead"] = entry
-    import common
-
-    data["provenance"] = common.provenance()
     path.write_text(json.dumps(data, indent=2) + "\n")
     print(f"wrote {path}")
     return entry
@@ -234,17 +237,25 @@ def main() -> None:
                          "re-time interleaved as the overhead baseline")
     ap.add_argument("--out", default=None,
                     help="output JSON path (default: repo-root BENCH_engine.json)")
-    ap.add_argument("--_cohort-worker", action="store_true",
-                    dest="cohort_worker", help=argparse.SUPPRESS)
+    ap.add_argument("--_worker", choices=("bench", "cohort"), default=None,
+                    dest="worker", help=argparse.SUPPRESS)
+    ap.add_argument("--scheme", default="fedavg")
+    ap.add_argument("--trainer", default="cohort")
     ap.add_argument("--task", choices=("cnn", "rnn"), default="rnn")
     ap.add_argument("--clients", type=int, default=24)
     ap.add_argument("--rounds", type=int, default=0)
     ap.add_argument("--warmup", type=int, default=2)
     args = ap.parse_args()
 
-    if args.cohort_worker:
-        res = bench_cohort_rounds(args.task, args.clients,
-                                  args.rounds or 5, args.warmup)
+    if args.worker:
+        import common
+
+        if args.worker == "bench":
+            res = bench(args.scheme, args.trainer, args.rounds, args.warmup)
+        else:
+            res = bench_cohort_rounds(args.task, args.clients,
+                                      args.rounds or 5, args.warmup)
+        res["provenance"] = common.provenance()
         print(json.dumps(res))
         return
 
@@ -262,8 +273,11 @@ def main() -> None:
     results = {}
     for scheme in ("fedavg", "heroes"):
         warmup = 1 if quick else (8 if scheme == "heroes" else 2)
-        seq = bench(scheme, "sequential", rounds, warmup)
-        coh = bench(scheme, "cohort", rounds, warmup)
+        seq, coh = (_run_worker(["bench", "--scheme", scheme,
+                                 "--trainer", trainer,
+                                 "--rounds", str(rounds),
+                                 "--warmup", str(warmup)])
+                    for trainer in ("sequential", "cohort"))
         results[scheme] = {
             "sequential_per_round_s": seq["per_round_s"],
             "cohort_per_round_s": coh["per_round_s"],
@@ -299,13 +313,11 @@ def main() -> None:
               f" ms/round   speedup {sh['speedup']:.2f}x "
               f"(best {sh['best_speedup']:.2f}x)")
 
-    import common
-
     out = {
         "benchmark": "engine_cohort_vs_sequential",
         "setup": {"model": "cnn", "num_clients": 10, "clients_per_round": 10,
                   "tau": 10, "batch_size": 16},
-        "provenance": common.provenance(),
+        "provenance": coh["provenance"],
         "results": results,
     }
     if sharded:

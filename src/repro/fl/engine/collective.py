@@ -41,7 +41,7 @@ from typing import Any, Dict, FrozenSet, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from repro.core import aggregation
 from repro.obs.recorder import NOOP

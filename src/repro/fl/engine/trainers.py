@@ -46,7 +46,7 @@ from typing import Dict, List, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import NamedSharding
 
 from repro.core import estimator
@@ -214,12 +214,16 @@ def _cohort_fns(model: FLModelDef, width: int, factorized: bool, mesh=None,
     # mesh variant: clients sharded P(COHORT_AXIS), lr replicated, the
     # batch pytree sharded on its client axis (position 1: (tau, C, B)).
     # Specs are pytree prefixes, so one spec covers each whole subtree.
+    # The bodies hold no collective, so there is no cross-device value
+    # whose variance along the axis needs checking; the check would
+    # reject scans whose initial carry is a constant (the flash
+    # attention accumulator) while the updated carry varies per device.
     cs, rs = flsh.contribution_spec(), flsh.replicated_spec()
     bs = flsh.client_axis_spec(1)
     train_sh = shard_map(train, mesh=mesh, in_specs=(cs, bs, cs, rs),
-                         out_specs=(cs, cs, cs))
+                         out_specs=(cs, cs, cs), check_vma=False)
     est_sh = shard_map(estimates, mesh=mesh, in_specs=(cs, cs, cs),
-                       out_specs=cs)
+                       out_specs=cs, check_vma=False)
     return jax.jit(train_sh), jax.jit(est_sh)
 
 

@@ -216,6 +216,7 @@ class FLModelDef:
         return out
 
     def compose_all(self, reduced, width: int) -> Dict[str, Array]:
+        reduced = _on_one_device(reduced)
         return {
             name: compose(reduced[name]["basis"], reduced[name]["coeff"], width, spec)
             for name, spec in self.specs.items()
@@ -373,6 +374,28 @@ class FLModelDef:
 
     def dense_bytes(self, width: int) -> int:
         return 4 * sum(s.params_materialized(width) for s in self.specs.values())
+
+
+def _on_one_device(tree):
+    """Concrete arrays spread over several devices, moved to one of them.
+
+    Server-side composition (evaluation, serving) runs eagerly on the
+    merged state, which the collective merge leaves replicated (or
+    block-sharded) over the cohort mesh.  XLA cannot partition a Pallas
+    kernel over a mesh, so a compose on such arrays must run on one
+    device; a replicated array already holds a full copy there.  Traced
+    values (compose inside a jitted or ``shard_map``ped loss) pass
+    through.
+    """
+    def one(x):
+        if isinstance(x, jax.core.Tracer) or not isinstance(x, jax.Array):
+            return x
+        devs = x.sharding.device_set
+        if len(devs) < 2:
+            return x
+        return jax.device_put(x, min(devs, key=lambda d: d.id))
+
+    return jax.tree_util.tree_map(one, tree)
 
 
 # ---------------------------------------------------------------------------

@@ -42,6 +42,7 @@ import numpy as np
 
 from repro.fl.models import (ComposedLayer, CompositionSpec, FLModelDef,
                              LayerHint, register_model)
+from repro.kernels.compose import default_interpret
 from repro.kernels.decode_attention import decode_attention_pallas
 from repro.models.attention import apply_rotary, flash_attention, rope_angles
 
@@ -267,17 +268,17 @@ def greedy_decode(model: FLModelDef, weights: Dict[str, Array], width: int,
 
     prompt (B, T0) int32; generates ``steps`` tokens.  ``backend``
     selects the attention kernel: ``"pallas"`` streams the KV cache
-    through :func:`decode_attention_pallas` (interpret mode on CPU
-    hosts, compiled on TPU), ``"xla"`` is the inline reference used as
-    the parity oracle.  The prompt is prefilled through the same decode
-    step, so the kernel serves every position.
+    through :func:`decode_attention_pallas` (compiled on TPU, interpret
+    elsewhere — :func:`default_interpret`), ``"xla"`` is the inline
+    reference used as the parity oracle.  The prompt is prefilled
+    through the same decode step, so the kernel serves every position.
 
     Returns ``(tokens (B, steps), last_logits (B, V))``.
     """
     if backend not in ("pallas", "xla"):
         raise ValueError(f"unknown decode backend {backend!r}")
     if interpret is None:
-        interpret = jax.default_backend() == "cpu"
+        interpret = default_interpret()
     arch = arch_of(model)
     prompt = jnp.asarray(prompt, dtype=jnp.int32)
     B, t0 = prompt.shape

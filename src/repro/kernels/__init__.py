@@ -22,13 +22,14 @@ compiled lowering for these kernel bodies — see
 ``ops`` holds the jit'd public wrappers; ``ref`` the pure-jnp oracles the
 sweep tests assert against (tests/test_kernels.py).
 
-Audit note: every kernel above is either on an engine hot path
-(compose / rank_dense_apply / conv_rank_apply / compose_dense_apply via
-``forward_impl`` dispatch, flash/decode attention via the transformer
-train + serve stacks) or a tested reference implementation kept for the
-model zoo (ssd_chunk, rmsnorm — ``repro.models`` currently uses plain
-jnp formulations at its small shapes; the kernels stay oracle-verified
-so swapping them in is a one-line change when shapes grow).
+Audit note: on the engine's path are compose / rank_dense_apply /
+conv_rank_apply / compose_dense_apply (``forward_impl`` dispatch) and
+decode_attention (``repro.fl.transformer.greedy_decode``).  The
+engine's transformer trains through the jnp
+``repro.models.attention.flash_attention``, not the Pallas flash kernel
+here; flash_attention, ssd_chunk and rmsnorm are oracle-tested but on no
+engine path.  The five on the path compile for a TPU v5e
+(tests/test_tpu_compile.py) and run there in ``chip_smoke.py``.
 """
 
 from repro.kernels import ops, ref  # noqa: F401

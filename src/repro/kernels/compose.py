@@ -223,13 +223,16 @@ def compose_pallas(basis: Array, coeff: Array, *, block_i: int = 128,
 
 
 def _rank_apply_kernel(x_ref, v_ref, u_ref, o_ref):
-    # x_ref (bm, g, I), v_ref (I, R), u_ref (g*R, D) -> o_ref (bm, D)
-    bm, g, I = x_ref.shape
-    t = jnp.dot(x_ref[...].reshape(bm * g, I), v_ref[...],
-                preferred_element_type=jnp.float32)
-    t = t.reshape(bm, g * v_ref.shape[1]).astype(x_ref.dtype)
-    y = jnp.dot(t, u_ref[...], preferred_element_type=jnp.float32)
-    o_ref[...] = y.astype(o_ref.dtype)
+    # x_ref (bm, g, I), v_ref (I, R), u_ref (g, R, D) -> o_ref (bm, D)
+    # One (bm, R) rank slice per input group: y = sum_a (x_a·v)·û_a.
+    # Mosaic cannot fold (bm, g, I) into bm·g rows, so the groups loop.
+    bm, g, _ = x_ref.shape
+    acc = jnp.zeros((bm, o_ref.shape[1]), jnp.float32)
+    for a in range(g):
+        t = jnp.dot(x_ref[:, a, :], v_ref[...],
+                    preferred_element_type=jnp.float32).astype(x_ref.dtype)
+        acc = acc + jnp.dot(t, u_ref[a], preferred_element_type=jnp.float32)
+    o_ref[...] = acc.astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("block_m", "interpret"))
@@ -240,7 +243,9 @@ def rank_apply_pallas(xg: Array, v2: Array, u2: Array, *,
     -> (M, D); the (M, g*R) rank intermediate stays in VMEM."""
     interpret = _resolve(interpret)
     M, g, I = xg.shape
+    R = v2.shape[1]
     D = u2.shape[1]
+    u3 = u2.reshape(g, R, D)
     bm = min(block_m, M)
     Mp = -(-M // bm) * bm
     xp = jnp.pad(xg, ((0, Mp - M), (0, 0), (0, 0)))
@@ -249,13 +254,13 @@ def rank_apply_pallas(xg: Array, v2: Array, u2: Array, *,
         grid=(Mp // bm,),
         in_specs=[
             pl.BlockSpec((bm, g, I), lambda i: (i, 0, 0)),
-            pl.BlockSpec((I, v2.shape[1]), lambda i: (0, 0)),
-            pl.BlockSpec(u2.shape, lambda i: (0, 0)),
+            pl.BlockSpec((I, R), lambda i: (0, 0)),
+            pl.BlockSpec((g, R, D), lambda i: (0, 0, 0)),
         ],
         out_specs=pl.BlockSpec((bm, D), lambda i: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((Mp, D), xg.dtype),
         interpret=interpret,
-    )(xp, v2, u2)
+    )(xp, v2, u3)
     return out[:M]
 
 

@@ -10,12 +10,13 @@ overhead forced a hardcoded CPU gate that kept ``forward_impl="auto"``
 off the conv rank path entirely.  This module fuses the two stages:
 
 ``conv_rank_pallas``
-    one Pallas kernel per batch image: the basis conv runs as k²
-    shifted matmuls over the padded image held in VMEM, the rank
-    intermediate never leaves VMEM, and the same kernel invocation
-    contracts it against the ``(g·R, D)`` coefficient matrix.  Grid is
-    the batch dimension; compiled on TPU, ``interpret=True`` elsewhere
-    (``interpret=None`` resolves through
+    one Pallas kernel over (image, output row): the basis conv runs as
+    k² shifted matmuls per input group over the padded image held in
+    VMEM (split into its stride phases and groups outside the kernel,
+    so every window is a contiguous slice), the rank intermediate
+    never leaves VMEM, and the same kernel step contracts it against
+    the coefficient blocks.  Compiled on TPU, ``interpret=True``
+    elsewhere (``interpret=None`` resolves through
     :func:`repro.kernels.compose.default_interpret`).
 
 ``conv_rank_apply``
@@ -149,31 +150,49 @@ def _fused_math(x: Array, basis: Array, u: Array, p: int, mode: str,
     return t2 @ u2
 
 
-def _conv_rank_kernel(x_ref, v_ref, u_ref, o_ref, *, k, stride, g, Ho, Wo):
-    """Per-image fused body: k² shifted matmuls (I→R) + contraction.
+def _conv_rank_kernel(x_ref, v_ref, u_ref, o_ref, *, k, stride, g):
+    """One output row of one image: k² shifted matmuls (I→R) per input
+    group, then the coefficient contraction.
 
-    x_ref (1, Hp, Wp, g·I) — the SAME-padded image; v_ref (ksq, I, R);
-    u_ref (g·R, D); o_ref (1, Ho, Wo, D).  The (Ho·Wo, g·R) rank
-    intermediate lives only in VMEM/registers.
+    x_ref (1, s²·g, Hq, Wq, I) — the SAME-padded image split into its
+    s×s stride phases and its g input groups (see
+    :func:`_phase_split`), so every window is a contiguous slice and no
+    lane or sublane reshape is needed; v_ref (ksq, I, R); u_ref
+    (g, R, D); o_ref (1, 1, Wo, D).  The (Wo, g·R) rank intermediate
+    lives only in VMEM/registers.
     """
-    xp = x_ref[0]
-    Hp, Wp, _ = xp.shape
-    I, R = v_ref.shape[1], v_ref.shape[2]
-    xg = xp.reshape(Hp, Wp, g, I)
-    acc = jnp.zeros((Ho * Wo * g, R), jnp.float32)
-    for ky in range(k):
-        for kx in range(k):
-            win = jax.lax.slice(
-                xg, (ky, kx, 0, 0),
-                (ky + stride * (Ho - 1) + 1, kx + stride * (Wo - 1) + 1,
-                 g, I),
-                (stride, stride, 1, 1))
-            acc = acc + jnp.dot(win.reshape(Ho * Wo * g, I),
-                                v_ref[ky * k + kx],
-                                preferred_element_type=jnp.float32)
-    t = acc.reshape(Ho * Wo, g * R).astype(x_ref.dtype)
-    y = jnp.dot(t, u_ref[...], preferred_element_type=jnp.float32)
-    o_ref[0] = y.reshape(Ho, Wo, u_ref.shape[1]).astype(o_ref.dtype)
+    h = pl.program_id(1)
+    Wo = o_ref.shape[2]
+    y = jnp.zeros((Wo, o_ref.shape[3]), jnp.float32)
+    for a in range(g):
+        acc = jnp.zeros((Wo, v_ref.shape[2]), jnp.float32)
+        for ky in range(k):
+            for kx in range(k):
+                phase = ((ky % stride) * stride + kx % stride) * g + a
+                win = x_ref[0, phase, h + ky // stride,
+                            pl.ds(kx // stride, Wo), :]  # (Wo, I)
+                acc = acc + jnp.dot(win, v_ref[ky * k + kx],
+                                    preferred_element_type=jnp.float32)
+        t = acc.astype(x_ref.dtype)
+        y = y + jnp.dot(t, u_ref[a], preferred_element_type=jnp.float32)
+    o_ref[0, 0] = y.astype(o_ref.dtype)
+
+
+def _phase_split(xp: Array, stride: int, g: int) -> Array:
+    """(N, Hp, Wp, g·I) padded image -> (N, s²·g, Hq, Wq, I).
+
+    Row ``y`` of the padded image is row ``y // s`` of phase ``y % s``
+    (likewise for columns), so the stride-s window of tap (ky, kx) is
+    the contiguous slice starting at ``(ky // s, kx // s)`` of phase
+    ``(ky % s, kx % s)``.
+    """
+    N, Hp, Wp, C = xp.shape
+    s = stride
+    Hq, Wq = -(-Hp // s), -(-Wp // s)
+    xp = jnp.pad(xp, ((0, 0), (0, Hq * s - Hp), (0, Wq * s - Wp), (0, 0)))
+    xq = xp.reshape(N, Hq, s, Wq, s, g, C // g)
+    xq = jnp.transpose(xq, (0, 2, 4, 5, 1, 3, 6))
+    return xq.reshape(N, s * s * g, Hq, Wq, C // g)
 
 
 @functools.partial(jax.jit,
@@ -184,37 +203,38 @@ def conv_rank_pallas(x: Array, basis: Array, u2: Array, *, p: int,
     """Fused conv rank kernel: x (N, H, W, g·I) × basis (ksq, I, R) ×
     u2 (g·R, D) -> (N, Ho, Wo, D).
 
-    One grid step per batch image; the whole padded image plus both
-    factor operands sit in VMEM (the engine's model shapes are a few KB
-    per image — far under the VMEM budget).  ``interpret=None``
-    resolves via :func:`default_interpret` (compiled on TPU, interpret
-    elsewhere; the interpret path is CI's parity harness, not a
-    production path — CPU production uses :func:`_fused_math`).
+    Grid (image, output row); the whole phase-split padded image plus
+    both factor operands sit in VMEM (the engine's model shapes are a
+    few KB per image — far under the VMEM budget) and stay resident
+    across an image's rows.  ``interpret=None`` resolves via
+    :func:`default_interpret` (compiled on TPU, interpret elsewhere; the
+    interpret path is CI's parity harness, not a production path — CPU
+    production uses :func:`_fused_math`).
     """
     interpret = _resolve(interpret)
-    ksq, I, R = basis.shape
+    ksq, _, R = basis.shape
     k = int(round(ksq ** 0.5))
     g = 1 if mode == "grow_out" else p
-    N, H, W, C = x.shape
+    N, H, W, _ = x.shape
     D = u2.shape[1]
     Ho, (ph_lo, ph_hi) = _same_pads(H, k, stride)
     Wo, (pw_lo, pw_hi) = _same_pads(W, k, stride)
     xp = jnp.pad(x, ((0, 0), (ph_lo, ph_hi), (pw_lo, pw_hi), (0, 0)))
-    Hp, Wp = xp.shape[1], xp.shape[2]
-    kern = functools.partial(_conv_rank_kernel, k=k, stride=stride, g=g,
-                             Ho=Ho, Wo=Wo)
+    xq = _phase_split(xp, stride, g)
+    u3 = u2.reshape(g, R, D)
+    kern = functools.partial(_conv_rank_kernel, k=k, stride=stride, g=g)
     return pl.pallas_call(
         kern,
-        grid=(N,),
+        grid=(N, Ho),
         in_specs=[
-            pl.BlockSpec((1, Hp, Wp, C), lambda n: (n, 0, 0, 0)),
-            pl.BlockSpec(basis.shape, lambda n: (0, 0, 0)),
-            pl.BlockSpec(u2.shape, lambda n: (0, 0)),
+            pl.BlockSpec((1,) + xq.shape[1:], lambda n, h: (n, 0, 0, 0, 0)),
+            pl.BlockSpec(basis.shape, lambda n, h: (0, 0, 0)),
+            pl.BlockSpec(u3.shape, lambda n, h: (0, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, Ho, Wo, D), lambda n: (n, 0, 0, 0)),
+        out_specs=pl.BlockSpec((1, 1, Wo, D), lambda n, h: (n, h, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((N, Ho, Wo, D), x.dtype),
         interpret=interpret,
-    )(xp, basis, u2)
+    )(xq, basis, u3)
 
 
 @functools.lru_cache(maxsize=None)

@@ -20,6 +20,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 import jax.experimental.pallas.tpu as pltpu
 
+from repro.kernels.compose import _resolve
+
 NEG_INF = -1e30
 
 
@@ -42,29 +44,34 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref,
     valid = kpos < len_ref[b]
     s = jnp.where(valid, s, NEG_INF)
 
-    m_prev = m_ref[...]
-    m_new = jnp.maximum(m_prev, jnp.max(s))
+    # running max / sum live in (1, 1) VMEM tiles: Mosaic cannot index
+    # a rank-0 ref
+    m_prev = m_ref[...]  # (1, 1)
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
     p = jnp.exp(s - m_new)
     corr = jnp.exp(m_prev - m_new)
-    l_ref[...] = l_ref[...] * corr + jnp.sum(p)
+    l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=-1, keepdims=True)
     acc_ref[...] = acc_ref[...] * corr + jnp.dot(
-        p.astype(v.dtype), v, preferred_element_type=jnp.float32
-    )[0]
+        p.astype(v.dtype), v, preferred_element_type=jnp.float32)  # (1, D)
     m_ref[...] = m_new
 
     @pl.when(j == nk - 1)
     def _fin():
         o_ref[0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)).astype(
-            o_ref.dtype
-        )[None]
+            o_ref.dtype)
 
 
 @functools.partial(
     jax.jit, static_argnames=("kv_block", "q_per_kv", "interpret")
 )
 def decode_attention_pallas(q, k, v, lengths, *, kv_block: int = 512,
-                            q_per_kv: int = 1, interpret: bool = True):
-    """q (BH, D); k/v (BKV, S, D); lengths (BH,) int32 -> (BH, D)."""
+                            q_per_kv: int = 1, interpret: bool | None = None):
+    """q (BH, D); k/v (BKV, S, D); lengths (BH,) int32 -> (BH, D).
+
+    ``interpret=None`` resolves via :func:`repro.kernels.compose.
+    default_interpret` (compiled on TPU, interpret elsewhere).
+    """
+    interpret = _resolve(interpret)
     BH, D = q.shape
     BKV, S, _ = k.shape
     assert BH == BKV * q_per_kv
@@ -87,9 +94,9 @@ def decode_attention_pallas(q, k, v, lengths, *, kv_block: int = 512,
         ],
         out_specs=pl.BlockSpec((1, 1, D), lambda b, j, lens: (b, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((), jnp.float32),
-            pltpu.VMEM((), jnp.float32),
-            pltpu.VMEM((D,), jnp.float32),
+            pltpu.VMEM((1, 1), jnp.float32),
+            pltpu.VMEM((1, 1), jnp.float32),
+            pltpu.VMEM((1, D), jnp.float32),
         ],
     )
     out = pl.pallas_call(

@@ -9,6 +9,16 @@ see the 1 real CPU device.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape, axes):
+    """A mesh whose axes are ``Auto``: the sharding rules here annotate
+    parameters and constrain activations, and leave the rest (gathers,
+    the loss) to the partitioner.  ``jax.make_mesh`` now defaults to
+    ``Explicit`` axes, under which an embedding gather from a sharded
+    table has no unambiguous output sharding."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -16,9 +26,9 @@ def make_production_mesh(*, multi_pod: bool = False):
     multi-pod adds a leading pod axis (2, 16, 16) = 512 chips."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh():
     """Degenerate 1-device mesh for CPU tests/examples."""
-    return jax.make_mesh((1, 1), ("data", "model"))
+    return _auto_mesh((1, 1), ("data", "model"))

@@ -17,6 +17,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import configs
+from repro.compile_cache import enable_compile_cache
 from repro.models import model
 
 
@@ -31,6 +32,7 @@ def main() -> None:
     ap.add_argument("--temperature", type=float, default=0.0)
     ap.add_argument("--top-k", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = configs.get_smoke(args.arch) if args.smoke else configs.get_config(args.arch)
     if cfg.family == "audio":

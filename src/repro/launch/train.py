@@ -21,6 +21,7 @@ import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro import configs
+from repro.compile_cache import enable_compile_cache
 from repro.checkpoint import restore_latest, save_checkpoint
 from repro.configs.base import CompositionConfig
 from repro.data import SyntheticTextTask, lm_batches
@@ -49,6 +50,7 @@ def main() -> None:
     ap.add_argument("--ckpt-dir", default=None)
     ap.add_argument("--ckpt-every", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = configs.get_smoke(args.arch) if args.smoke else configs.get_config(args.arch)
     if args.composition:

@@ -29,7 +29,7 @@ from typing import Any, Dict
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 Array = jax.Array
@@ -110,8 +110,11 @@ def apply_moe_shardmap(params: Params, cfg, x: Array, mesh,
                   P(model_axis, None, None), P(data_axis, None, None)),
         out_specs=P(data_axis, None, None),
     )
-    y = f(params["router"]["w"], params["gate"], params["up"],
-          params["down"], x)
+    # an Explicit-axis mesh (``jax.make_mesh``'s default) needs the mesh
+    # context for the arrays the body builds, e.g. the searchsorted bins
+    with jax.set_mesh(mesh):
+        y = f(params["router"]["w"], params["gate"], params["up"],
+              params["down"], x)
     if "shared" in params:
         from repro.models import layers
         y = y + layers.apply_mlp(params["shared"], x, cfg.activation)

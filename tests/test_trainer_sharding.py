@@ -288,3 +288,38 @@ def test_sharded_cohort_trainer_spmd():
     r = _run_subprocess(SHARDED_SCRIPT)
     assert r.returncode == 0, r.stderr[-3000:]
     assert "SHARDED_TRAINER_OK" in r.stdout
+
+
+ONE_DEVICE_COMPOSE_SCRIPT = textwrap.dedent("""
+    import os
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    import jax
+    import numpy as np
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from repro.fl import make_transformer
+    from repro.sharding import fl as flsh
+
+    # the collective merge leaves the server state replicated (or
+    # block-sharded) over the cohort mesh; eval and serving compose it
+    # eagerly, and a Pallas compose cannot be partitioned by XLA
+    model = make_transformer()
+    params = model.init_factorized(jax.random.PRNGKey(0))
+    mesh = flsh.cohort_mesh()
+    assert mesh.devices.size == 4
+    spread = jax.device_put(params, NamedSharding(mesh, P()))
+    assert len(spread["head"]["coeff"].sharding.device_set) == 4
+    ids = np.arange(9)
+    w4 = model.compose_all(model.reduce(spread, 3, ids, ids[:3]), 3)
+    w1 = model.compose_all(model.reduce(params, 3, ids, ids[:3]), 3)
+    for name in w1:
+        assert len(w4[name].sharding.device_set) == 1, name
+        np.testing.assert_array_equal(np.asarray(w4[name]),
+                                      np.asarray(w1[name]))
+    print("ONE_DEVICE_COMPOSE_OK")
+""")
+
+
+def test_compose_all_moves_mesh_state_to_one_device():
+    r = _run_subprocess(ONE_DEVICE_COMPOSE_SCRIPT)
+    assert r.returncode == 0, r.stderr[-3000:]
+    assert "ONE_DEVICE_COMPOSE_OK" in r.stdout
