@@ -113,18 +113,15 @@ def bench_sharded_cohort(task: str, clients: int, rounds: int, warmup: int,
     return out
 
 
-def _run_worker(argv: list, devices: int = 1,
-                script: str | None = None) -> dict:
+def _run_worker(argv: list, devices: int = 1) -> dict:
     """Run one timed leg in a fresh process and return its JSON result.
 
     The worker is the only process that initialises jax, with
-    ``devices`` forced host devices.  ``script`` points at another
-    checkout's bench_engine.py to time a different revision (the worker
-    is self-contained: it inserts its own repo's ``src`` on sys.path).
+    ``devices`` forced host devices.
     """
     env = {**os.environ,
            "XLA_FLAGS": f"--xla_force_host_platform_device_count={devices}"}
-    cmd = [sys.executable, script or __file__, "--_worker", *argv]
+    cmd = [sys.executable, __file__, "--_worker", *argv]
     r = subprocess.run(cmd, env=env, capture_output=True, text=True)
     if r.returncode != 0:
         raise RuntimeError(f"worker {argv} ({devices} devices) failed:\n"
@@ -133,94 +130,12 @@ def _run_worker(argv: list, devices: int = 1,
 
 
 def _run_cohort_worker(task: str, clients: int, rounds: int, warmup: int,
-                       script: str | None = None, devices: int = 1) -> dict:
+                       devices: int = 1) -> dict:
     """One cohort-round measurement in a fresh process (the protocol
     every stored per-round baseline in BENCH_engine.json uses)."""
     return _run_worker(["cohort", "--task", task, "--clients", str(clients),
                         "--rounds", str(rounds), "--warmup", str(warmup)],
-                       devices=devices, script=script)
-
-
-def bench_telemetry_overhead(path: Path, quick: bool, clients: int,
-                             rounds: int,
-                             baseline_root: str | None = None) -> dict:
-    """Measure the no-op-recorder cost and merge a ``telemetry_overhead``
-    entry into the existing ``BENCH_engine.json`` (read-modify-write).
-
-    The engine's hot loops are instrumented; with telemetry off every
-    call routes to the shared no-op recorder.  With ``baseline_root``
-    (a checkout of the pre-instrumentation revision) the baseline is
-    re-timed *interleaved* with the instrumented code in this session —
-    the only comparison tight enough for a 2% bar; cross-session numbers
-    drift ~10% with box load.  Without it, ratios fall back to the
-    stored ``post_refactor_serverstate`` per-round baselines (noisy —
-    treat as indicative only).
-    """
-    import statistics
-
-    data = json.loads(path.read_text()) if path.exists() else {}
-    stored = data.get("post_refactor_serverstate", {})
-    repeats = 1 if quick else 3
-    base_script = None
-    if baseline_root:
-        base_script = str(Path(baseline_root).resolve()
-                          / "benchmarks" / "bench_engine.py")
-        baseline_note = ("baseline re-timed interleaved from the "
-                         "pre-instrumentation checkout at "
-                         f"{baseline_root}")
-    else:
-        baseline_note = ("baseline from stored post_refactor_serverstate "
-                         "(different session — noisy)")
-    entry = {"note": "instrumented engine with telemetry='off' (no-op "
-                     "recorder) vs the uninstrumented engine; ratio <= "
-                     "1.02 = the default recorder is free; "
-                     + baseline_note}
-    for task in ("rnn", "cnn"):
-        ours, theirs = [], []
-        for _ in range(repeats):
-            if base_script:  # interleave A/B within the session
-                theirs.append(_run_cohort_worker(
-                    task, clients, rounds, 2, base_script)["per_round_s"])
-            res = _run_cohort_worker(task, clients, rounds, 2)
-            ours.append(res["per_round_s"])
-            data["provenance"] = res["provenance"]
-        per_round = statistics.median(ours)
-        cell = {"per_round_s": per_round, "clients": clients, "tau": 10,
-                "rounds": rounds, "repeats": repeats,
-                "protocol": "median-of-%d%s, 1 device, cohort trainer, "
-                            "telemetry=off"
-                            % (repeats,
-                               " interleaved" if base_script else "")}
-        if theirs:
-            # paired per-repeat ratios: adjacent A/B workers share box
-            # conditions, so the ratio cancels load drift that the raw
-            # medians (each +-10-20% on a shared box) cannot
-            pair = [o / t for o, t in zip(ours, theirs)]
-            ref = statistics.median(theirs)
-            cell["baseline_per_round_s"] = ref
-            cell["overhead_vs_baseline"] = statistics.median(pair)
-            cell["best_overhead_vs_baseline"] = min(ours) / min(theirs)
-            cell["paired_ratios"] = pair
-            print(f"telemetry-off {task}: {per_round*1e3:8.1f} ms/round   "
-                  f"baseline {ref*1e3:8.1f} ms/round   paired-median "
-                  f"{cell['overhead_vs_baseline']:.3f}x   best "
-                  f"{cell['best_overhead_vs_baseline']:.3f}x")
-        else:
-            ref = stored.get(task, {}).get("per_round_s")
-            if ref:
-                cell["baseline_per_round_s"] = ref
-                cell["overhead_vs_baseline"] = per_round / ref
-                print(f"telemetry-off {task}: {per_round*1e3:8.1f} ms/round"
-                      f"   baseline {ref*1e3:8.1f} ms/round   "
-                      f"ratio {per_round/ref:.3f}x")
-            else:
-                print(f"telemetry-off {task}: {per_round*1e3:8.1f} ms/round"
-                      "   (no stored baseline)")
-        entry[task] = cell
-    data["telemetry_overhead"] = entry
-    path.write_text(json.dumps(data, indent=2) + "\n")
-    print(f"wrote {path}")
-    return entry
+                       devices=devices)
 
 
 def main() -> None:
@@ -229,12 +144,6 @@ def main() -> None:
                     help="fewer repeated rounds (CI smoke)")
     ap.add_argument("--smoke", action="store_true",
                     help="minimal rounds incl. the sharded-cohort shape")
-    ap.add_argument("--telemetry-only", action="store_true",
-                    help="only (re)measure the no-op telemetry overhead "
-                         "and merge it into the existing BENCH_engine.json")
-    ap.add_argument("--baseline-root", default=None,
-                    help="checkout of the pre-instrumentation revision to "
-                         "re-time interleaved as the overhead baseline")
     ap.add_argument("--out", default=None,
                     help="output JSON path (default: repo-root BENCH_engine.json)")
     ap.add_argument("--_worker", choices=("bench", "cohort"), default=None,
@@ -257,14 +166,6 @@ def main() -> None:
                                       args.rounds or 5, args.warmup)
         res["provenance"] = common.provenance()
         print(json.dumps(res))
-        return
-
-    if args.telemetry_only:
-        path = Path(args.out) if args.out else \
-            Path(__file__).resolve().parents[1] / "BENCH_engine.json"
-        bench_telemetry_overhead(path, args.fast or args.smoke,
-                                 args.clients, args.rounds or 5,
-                                 baseline_root=args.baseline_root)
         return
 
     quick = args.fast or args.smoke
@@ -324,12 +225,12 @@ def main() -> None:
         out["sharded_cohort"] = sharded
     path = Path(args.out) if args.out else \
         Path(__file__).resolve().parents[1] / "BENCH_engine.json"
-    # full rewrites keep previously merged sections (stored baselines,
-    # telemetry overhead) — they are reference points, not rerun here
+    # full rewrites keep the stored baselines — reference points, not
+    # rerun here
     if path.exists():
         try:
             old = json.loads(path.read_text())
-            for k in ("post_refactor_serverstate", "telemetry_overhead"):
+            for k in ("post_refactor_serverstate",):
                 if k in old and k not in out:
                     out[k] = old[k]
         except (ValueError, OSError):

@@ -23,6 +23,7 @@ import numpy as np
 
 from repro.core import estimator
 from repro.fl.models import FLModelDef
+from repro.obs.recorder import NOOP
 
 Array = jax.Array
 
@@ -98,6 +99,7 @@ def local_train(
     estimate: bool = True,
     forward_impl: str = "auto",
     calibration=None,
+    obs=NOOP,
 ) -> ClientResult:
     """tau local SGD iterations (Alg. 2 lines 4-9).
 
@@ -107,6 +109,8 @@ def local_train(
     applies factors in rank space wherever the measured cost model says
     it is cheaper (``calibration`` carries an FLConfig override; None =
     the per-process measurement).  Ignored when ``factorized=False``.
+    ``obs`` records the ``trainer.sgd``, ``trainer.loss`` and
+    ``trainer.estimate`` wall spans (nothing with the default no-op).
     """
     loss_jit, grad_fn, sgd_step = _jitted_fns(model, width, factorized,
                                               forward_impl, calibration)
@@ -114,23 +118,27 @@ def local_train(
     params = params0
     n = len(y)
     first_batch = None
-    for _ in range(max(tau, 1)):
-        idx = rng.integers(0, n, min(batch_size, n))
-        batch = data_batch(model, x, y, idx)
-        if first_batch is None:
-            first_batch = batch
-        params = sgd_step(params, batch, lr)
+    with obs.wall_span("trainer.sgd"):
+        for _ in range(max(tau, 1)):
+            idx = rng.integers(0, n, min(batch_size, n))
+            batch = data_batch(model, x, y, idx)
+            if first_batch is None:
+                first_batch = batch
+            params = sgd_step(params, batch, lr)
 
     est = {}
-    loss_b = float(loss_jit(params0, first_batch))
-    loss_a = float(loss_jit(params, first_batch))
+    with obs.wall_span("trainer.loss"):
+        loss_b = float(loss_jit(params0, first_batch))
+        loss_a = float(loss_jit(params, first_batch))
     if estimate:
-        batches = [
-            data_batch(model, x, y, rng.integers(0, n, min(batch_size, n)))
-            for _ in range(3)
-        ]
-        est = estimator.client_estimates(
-            lambda p, b: grad_fn(p, b), params0, params, batches
-        )
-        est = {k: float(v) for k, v in est.items()}
+        with obs.wall_span("trainer.estimate"):
+            batches = [
+                data_batch(model, x, y,
+                           rng.integers(0, n, min(batch_size, n)))
+                for _ in range(3)
+            ]
+            est = estimator.client_estimates(
+                lambda p, b: grad_fn(p, b), params0, params, batches
+            )
+            est = {k: float(v) for k, v in est.items()}
     return ClientResult(params, est, loss_b, loss_a)

@@ -240,12 +240,26 @@ class CollectiveMerger:
         self._mesh_fns: Dict[Any, Any] = {}
         # telemetry recorder (rebound by the engine runner); merge
         # *latency* is spanned at the loop level ("aggregate.merge"),
-        # the merger itself counts per-rule compiled-merge invocations
+        # the merger spans its two stages inside it ("merge.prep",
+        # "merge.compiled") and counts per-rule compiled-merge calls and
+        # the host bytes each call ships ("merge.h2d_bytes")
         self.obs = NOOP
 
     def _count(self, rule: str) -> None:
         if self.obs.enabled:
             self.obs.counter_add("aggregate.collective_calls", rule=rule)
+
+    def _compiled(self, finish, stacked, *args):
+        """``finish(stacked, *args)`` under ``merge.compiled``, adding the
+        bytes of the numpy leaves it hands to the device (the host-prep
+        contributions; device-resident leaves add 0) to
+        ``merge.h2d_bytes`` and to the span."""
+        obs = self.obs
+        nbytes = (sum(v.nbytes for v in jax.tree_util.tree_leaves(stacked)
+                      if isinstance(v, np.ndarray)) if obs.enabled else 0)
+        obs.counter_add("merge.h2d_bytes", nbytes)
+        with obs.wall_span("merge.compiled", h2d_bytes=nbytes):
+            return finish(stacked, *args)
 
     # -- finish stage: dispatch the prepped stacks to a compiled merge.
     # Split out so subclasses can reroute the reduction topology (the
@@ -422,12 +436,13 @@ class CollectiveMerger:
             g[0].tree) for g in groups]
         return _rows_in_results_order(parts, [g[2] for g in groups], k_pad)
 
-    def _merge_factorized_device(self, prev_params, specs, groups, k: int,
+    def _stack_factorized_device(self, prev_params, specs, groups,
                                  k_pad: int, assigns):
-        """Factorized merge fed straight from device-resident stacks:
+        """Factorized merge inputs straight from device-resident stacks:
         coefficient rows become dense contributions through the compiled
         from-device scatter (one vmapped call per group/layer), bases
-        are row-gathers — the host never sees the trained params."""
+        are row-gathers — the host never sees the trained params.
+        Returns ``(stacked, shard_names)`` for ``_finish_fact``."""
         shard_names: FrozenSet[str] = frozenset()
         if self.shard_blocks:
             shard_names = frozenset(
@@ -455,7 +470,7 @@ class CollectiveMerger:
                 "mask": _rows_in_results_order(mask, positions, k_pad),
                 "prev": prev_c,
             }
-        return self._finish_fact(stacked, k, shard_names)
+        return stacked, shard_names
 
     # -- prep + dispatch ----------------------------------------------------
 
@@ -465,11 +480,19 @@ class CollectiveMerger:
         self._count("factorized")
         k = len(results)
         k_pad = flsh.pad_cohort(k, self.mesh)
+        with self.obs.wall_span("merge.prep", clients=k):
+            stacked, shard_names = self._stack_factorized(
+                prev_params, specs, results, assigns, weights, k_pad)
+        return self._compiled(self._finish_fact, stacked, k, shard_names)
+
+    def _stack_factorized(self, prev_params, specs, results, assigns,
+                          weights, k_pad: int):
+        """``merge_factorized``'s inputs: ``(stacked, shard_names)``."""
         if weights is None:
             groups = _device_groups(results)
             if groups is not None:
-                return self._merge_factorized_device(
-                    prev_params, specs, groups, k, k_pad, assigns)
+                return self._stack_factorized_device(
+                    prev_params, specs, groups, k_pad, assigns)
         results = _host_results(results)
         stacked: Dict[str, Dict[str, Any]] = {}
         for name, spec in specs.items():
@@ -504,18 +527,23 @@ class CollectiveMerger:
             shard_names = frozenset(
                 n for n, t in stacked.items()
                 if flsh.can_shard_blocks(t["prev"].shape[0], self.mesh))
-        return self._finish_fact(stacked, k, shard_names)
+        return stacked, shard_names
 
     def merge_dense_mean(self, prev_params, results, weights=None):
         """FedAvg/ADP: plain parameter mean over the cohort."""
         self._count("dense_mean")
         k = len(results)
         k_pad = flsh.pad_cohort(k, self.mesh)
+        with self.obs.wall_span("merge.prep", clients=k):
+            stacked = self._stack_dense(prev_params, results, weights, k_pad)
+        return self._compiled(self._finish_mean, stacked, k)
+
+    def _stack_dense(self, prev_params, results, weights, k_pad: int):
+        """``merge_dense_mean``'s stacked client trees."""
         if weights is None:
             groups = _device_groups(results)
             if groups is not None:
-                stacked = self._device_stacked(groups, k_pad)
-                return self._finish_mean(stacked, k)
+                return self._device_stacked(groups, k_pad)
         results = _host_results(results)
         prev_np = None
         trees = []
@@ -528,13 +556,18 @@ class CollectiveMerger:
                     prev_np = jax.tree_util.tree_map(np.asarray, prev_params)
                 trees.append(jax.tree_util.tree_map(
                     lambda u, g, w=w: _np_blend(u, w, g), r.params, prev_np))
-        stacked = jax.tree_util.tree_map(
+        return jax.tree_util.tree_map(
             lambda *xs: _pad_rows(np.stack(xs), k_pad), *trees)
-        return self._finish_mean(stacked, k)
 
     def merge_masked_dense(self, prev_params, results, weights=None):
         """HeteroFL: element-wise mean over the covering clients."""
         self._count("masked_dense")
+        with self.obs.wall_span("merge.prep", clients=len(results)):
+            stacked = self._stack_masked(prev_params, results, weights)
+        return self._compiled(self._finish_masked, stacked)
+
+    def _stack_masked(self, prev_params, results, weights):
+        """``merge_masked_dense``'s zero-padded regions and counts."""
         results = _host_results(results)
         k_pad = flsh.pad_cohort(len(results), self.mesh)
         stacked = {}
@@ -556,7 +589,7 @@ class CollectiveMerger:
             stacked[name] = {"padded": _pad_rows(np.stack(pads), k_pad),
                              "cnt": _pad_rows(np.stack(cnts), k_pad),
                              "prev": full}
-        return self._finish_masked(stacked)
+        return stacked
 
     def merge_flanc(self, basis, coeffs, results, widths, weights=None):
         """Flanc: shared basis mean + per-width coefficient means.
@@ -566,6 +599,23 @@ class CollectiveMerger:
         widths nobody trained keep their previous coefficients.
         """
         self._count("flanc")
+        k = len(results)
+        with self.obs.wall_span("merge.prep", clients=k):
+            stacked = self._stack_flanc(basis, coeffs, results, widths,
+                                        weights)
+        if self.mesh is not None:
+            return self._compiled(self._mesh_flanc_fn(), stacked,
+                                  jnp.float32(k))
+        new_basis, merged = self._compiled(_flanc_1d, stacked)
+        new_coeffs = dict(coeffs)
+        for p, g in merged.items():
+            new_coeffs[p] = g
+        return new_basis, new_coeffs
+
+    def _stack_flanc(self, basis, coeffs, results, widths, weights):
+        """``merge_flanc``'s inputs: per-width coefficient stacks, or on a
+        mesh one width-P zero-padded coefficient and a one-hot width row
+        per client."""
         results = _host_results(results)
         k = len(results)
         names = list(basis)
@@ -595,13 +645,8 @@ class CollectiveMerger:
                             c = _np_blend(c, w, np.asarray(coeffs[p][name]))
                         rows.append(c)
                     groups[p][name] = np.stack(rows)
-            new_basis, merged = _flanc_1d(
-                {"bases": {n: np.stack(b) for n, b in bases.items()},
-                 "groups": groups})
-            new_coeffs = dict(coeffs)
-            for p, g in merged.items():
-                new_coeffs[p] = g
-            return new_basis, new_coeffs
+            return {"bases": {n: np.stack(b) for n, b in bases.items()},
+                    "groups": groups}
 
         # mesh path: every client contributes ONE zero-padded dense coeff
         # (padded to the width-P block count) plus a one-hot width row;
@@ -620,7 +665,7 @@ class CollectiveMerger:
                 nb_max = coeffs[max_width][name].shape[0]
                 pad = [(0, nb_max - c.shape[0])] + [(0, 0)] * (c.ndim - 1)
                 dense[name].append(np.pad(c, pad))
-        stacked = {
+        return {
             "bases": {n: _pad_rows(np.stack(b), k_pad)
                       for n, b in bases.items()},
             "onehot": onehot,
@@ -628,7 +673,6 @@ class CollectiveMerger:
                       for n, rows in dense.items()},
             "prevs": {p: {n: coeffs[p][n] for n in names} for p in coeffs},
         }
-        return self._mesh_flanc_fn()(stacked, jnp.float32(k))
 
 
 def build_merger(cfg) -> CollectiveMerger:

@@ -82,9 +82,10 @@ class SyncRoundLoop(RoundLoop):
                 "participation scheduler returned an empty cohort "
                 f"(scheduler={type(eng.sampler).__name__}, "
                 f"num_clients={cfg.num_clients})")
-        state, assigns = eng.assignment.assign(state, clients)
-        results = eng.trainer.train_all(state, assigns)
         obs = eng.obs
+        with obs.wall_span("round.assign", clients=len(clients)):
+            state, assigns = eng.assignment.assign(state, clients)
+        results = eng.trainer.train_all(state, assigns)
         times = {}
         traffic = state.traffic
         up = 0.0
@@ -119,7 +120,8 @@ class SyncRoundLoop(RoundLoop):
                                     round=state.round + 1)
         acc = None
         if state.round % cfg.eval_every == 0 or state.round == 1:
-            acc = eng.aggregator.evaluate(state)
+            with obs.wall_span("round.evaluate", round=state.round):
+                acc = eng.aggregator.evaluate(state)
         if obs.enabled:
             obs.observe("round.makespan", makespan)
             obs.observe("round.wait", wait)
@@ -159,9 +161,10 @@ class SemiAsyncRoundLoop(RoundLoop):
     def _dispatch(self, state: ServerState,
                   clients: List[int]) -> ServerState:
         eng = self.eng
-        state, assigns = eng.assignment.assign(state, clients)
-        results = eng.trainer.train_all(state, assigns)
         obs = eng.obs
+        with obs.wall_span("round.assign", clients=len(clients)):
+            state, assigns = eng.assignment.assign(state, clients)
+        results = eng.trainer.train_all(state, assigns)
         traffic = state.traffic
         up = 0.0
         new = []
@@ -251,7 +254,8 @@ class SemiAsyncRoundLoop(RoundLoop):
                                     in_flight=remaining)
         acc = None
         if state.round % cfg.eval_every == 0 or state.round == 1:
-            acc = eng.aggregator.evaluate(state)
+            with obs.wall_span("round.evaluate", round=state.round):
+                acc = eng.aggregator.evaluate(state)
         if obs.enabled:
             obs.observe("round.makespan", makespan)
             obs.observe("round.wait", wait)

@@ -80,6 +80,18 @@ def _count_recompiles(obs, fn, before: Optional[int], **labels) -> None:
         obs.counter_add("trainer.jit_recompiles", after - before, **labels)
 
 
+def _pull(obs, params):
+    """Trained params to the host under ``trainer.pull``, adding their
+    bytes (array metadata, read before the copy) to
+    ``trainer.d2h_bytes`` and to the span."""
+    nbytes = (sum(int(v.nbytes) for v in jax.tree_util.tree_leaves(params))
+              if obs.enabled else 0)
+    with obs.wall_span("trainer.pull", d2h_bytes=nbytes):
+        host = jax.device_get(params)
+    obs.counter_add("trainer.d2h_bytes", nbytes)
+    return host
+
+
 class SequentialTrainer(LocalTrainer):
     """One ``local_train`` call per client (legacy-equivalent backend).
 
@@ -96,7 +108,8 @@ class SequentialTrainer(LocalTrainer):
         cal = for_dispatch(eng.cfg)
         out = {}
         for n, a in assigns.items():
-            params = eng.aggregator.client_params(state, n, a)
+            with obs.wall_span("trainer.client_params", client=int(n)):
+                params = eng.aggregator.client_params(state, n, a)
             before = None
             if obs.enabled:
                 # the per-step jits live in client._jitted_fns (lru
@@ -114,13 +127,13 @@ class SequentialTrainer(LocalTrainer):
                     eng.cfg.batch_size, factorized=eng.factorized,
                     estimate=eng.estimate,
                     forward_impl=eng.cfg.forward_impl,
-                    calibration=cal,
+                    calibration=cal, obs=obs,
                 )
             if obs.enabled:
                 _count_recompiles(obs, sgd_step, before,
                                   trainer="sequential",
                                   width=int(a["width"]))
-            out[n] = ClientResult(jax.device_get(res.params), res.estimates,
+            out[n] = ClientResult(_pull(obs, res.params), res.estimates,
                                   res.loss_before, res.loss_after)
         return out
 
@@ -415,7 +428,7 @@ class CohortTrainer(LocalTrainer):
                 out[n] = ClientResult(CohortSlice(stack, j), est,
                                       float(loss_b[j]), float(loss_a[j]))
             return out
-        final = jax.device_get(final)  # one transfer; slice per client below
+        final = _pull(obs, final)  # one transfer; slice per client below
         for j, n in enumerate(ns):
             params = jax.tree_util.tree_map(lambda v, j=j: v[j], final)
             est = {k: float(v[j]) for k, v in ests.items()} if ests else {}
@@ -478,36 +491,42 @@ class ProximalTrainer(LocalTrainer):
                 eng.model, a["width"], eng.factorized,
                 cfg.forward_impl, cal)
             before = _cache_size(prox_step) if obs.enabled else None
+            with obs.wall_span("trainer.client_params", client=int(n)):
+                anchor = eng.aggregator.client_params(state, n, a)
+            # the span tree of SequentialTrainer / client.local_train
             with obs.wall_span("trainer.local_train", client=int(n),
                                width=int(a["width"]), tau=int(a["tau"])):
-                anchor = eng.aggregator.client_params(state, n, a)
                 nsamp = eng.data.num_samples(n)
                 b_eff = min(cfg.batch_size, nsamp)
                 tau = max(a["tau"], 1)
                 idx, est_idx = round_batch_indices(cfg.seed, state.round, n,
                                                    nsamp, tau, b_eff,
                                                    estimate=eng.estimate)
-                params, first = anchor, None
-                for t in range(tau):
-                    xb, yb = eng.data.gather(n, idx[t])
-                    batch = {xkey: jnp.asarray(xb), "labels": jnp.asarray(yb)}
-                    if first is None:
-                        first = batch
-                    params = prox_step(params, anchor, batch, cfg.lr, mu)
+                with obs.wall_span("trainer.sgd"):
+                    params, first = anchor, None
+                    for t in range(tau):
+                        xb, yb = eng.data.gather(n, idx[t])
+                        batch = {xkey: jnp.asarray(xb),
+                                 "labels": jnp.asarray(yb)}
+                        if first is None:
+                            first = batch
+                        params = prox_step(params, anchor, batch, cfg.lr, mu)
+                with obs.wall_span("trainer.loss"):
+                    loss_b = float(loss_fn(anchor, first))
+                    loss_a = float(loss_fn(params, first))
                 est: Dict[str, float] = {}
                 if est_idx is not None:
-                    ebs = []
-                    for i in range(3):
-                        xb, yb = eng.data.gather(n, est_idx[i])
-                        ebs.append({xkey: jnp.asarray(xb),
-                                    "labels": jnp.asarray(yb)})
-                    est = estimator.client_estimates(grad_fn, anchor, params,
-                                                     ebs)
-                    est = {k: float(v) for k, v in est.items()}
-                out[n] = ClientResult(jax.device_get(params), est,
-                                      float(loss_fn(anchor, first)),
-                                      float(loss_fn(params, first)))
+                    with obs.wall_span("trainer.estimate"):
+                        ebs = []
+                        for i in range(3):
+                            xb, yb = eng.data.gather(n, est_idx[i])
+                            ebs.append({xkey: jnp.asarray(xb),
+                                        "labels": jnp.asarray(yb)})
+                        est = estimator.client_estimates(grad_fn, anchor,
+                                                         params, ebs)
+                        est = {k: float(v) for k, v in est.items()}
             if obs.enabled:
                 _count_recompiles(obs, prox_step, before, trainer="proximal",
                                   width=int(a["width"]))
+            out[n] = ClientResult(_pull(obs, params), est, loss_b, loss_a)
         return out

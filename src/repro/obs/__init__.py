@@ -15,7 +15,8 @@ Entry points::
     python -m repro.obs.trace  run_dir/events.jsonl t.json  # Perfetto
     python -m repro.obs.smoke                          # CI end-to-end
 
-See ``docs/OBSERVABILITY.md`` for the metric catalog.
+Wall-clock span names and meanings are in :data:`SPANS`; see
+``docs/OBSERVABILITY.md`` for the metric catalog.
 """
 
 from repro.obs.coverage import coverage_table, format_coverage
@@ -23,6 +24,7 @@ from repro.obs.recorder import (NOOP, NoopRecorder, Recorder, build_recorder,
                                 metric_key, runtime_provenance)
 from repro.obs.schema import validate_event, validate_events, validate_file
 from repro.obs.sinks import JsonlSink, MemorySink, Sink, load_events
+from repro.obs.spans import SPANS
 from repro.obs.trace import export_trace, to_trace_events
 
 __all__ = [
@@ -31,5 +33,5 @@ __all__ = [
     "Sink", "MemorySink", "JsonlSink", "load_events",
     "validate_event", "validate_events", "validate_file",
     "to_trace_events", "export_trace",
-    "coverage_table", "format_coverage",
+    "coverage_table", "format_coverage", "SPANS",
 ]

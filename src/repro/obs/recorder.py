@@ -10,7 +10,10 @@ One :class:`Recorder` serves a whole run.  It owns
   clock (simulated seconds: dispatch→train→upload per client) or the
   *wall* clock (``time.perf_counter``: merge latency, host staging,
   device steps, checkpoint writes) — fanned out to pluggable
-  :mod:`~repro.obs.sinks`.
+  :mod:`~repro.obs.sinks`.  A wall span records its ``parent`` (the
+  enclosing wall span on the same thread) and, while it is open, holds
+  a ``jax.profiler.TraceAnnotation`` of its name, so profiler traces
+  show it on the device trace's clock (names: :mod:`repro.obs.spans`).
 
 The registry mutates under one lock (the cohort trainer's prefetch
 worker records host-staging timings off the main thread); the event
@@ -63,9 +66,10 @@ _NULL_CTX = _NullCtx()
 
 
 class _WallSpan:
-    """Context manager recording one wall-clock span on exit."""
+    """Context manager recording one wall-clock span on exit, under a
+    profiler annotation of the same name."""
 
-    __slots__ = ("rec", "name", "attrs", "t0")
+    __slots__ = ("rec", "name", "attrs", "t0", "parent", "annotation")
 
     def __init__(self, rec: "Recorder", name: str, attrs: Dict[str, Any]):
         self.rec = rec
@@ -73,12 +77,23 @@ class _WallSpan:
         self.attrs = attrs
 
     def __enter__(self):
+        from jax.profiler import TraceAnnotation
+
+        stack = self.rec._open_spans()
+        self.parent = stack[-1] if stack else None
+        stack.append(self.name)
+        self.annotation = TraceAnnotation(self.name)
+        self.annotation.__enter__()
         self.t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
         t1 = time.perf_counter()
-        self.rec.span(self.name, self.t0, t1, clock="wall", **self.attrs)
+        self.annotation.__exit__(*exc)
+        self.rec._open_spans().pop()
+        self.rec._emit({"type": "span", "name": self.name, "clock": "wall",
+                        "t0": self.t0, "t1": t1, "parent": self.parent,
+                        "attrs": self.attrs})
         self.rec.observe(f"{self.name}_s", t1 - self.t0)
         return False
 
@@ -96,6 +111,7 @@ class Recorder:
         self.gauges: Dict[str, float] = {}
         self.histograms: Dict[str, List[float]] = {}
         self.tallies: Dict[str, np.ndarray] = {}
+        self._local = threading.local()  # open wall-span names per thread
         self._closed = False
         if meta is not None:
             self._emit({"type": "meta", "schema": SCHEMA_VERSION, **meta})
@@ -123,8 +139,16 @@ class Recorder:
 
     def wall_span(self, name: str, **attrs):
         """``with rec.wall_span("aggregate.merge"): ...`` — records the
-        span on the wall clock plus a ``<name>_s`` histogram entry."""
+        span on the wall clock, with its ``parent``, plus a ``<name>_s``
+        histogram entry; a ``jax.profiler`` trace taken meanwhile holds
+        it as a host event of the same name."""
         return _WallSpan(self, name, attrs)
+
+    def _open_spans(self) -> List[str]:
+        stack = getattr(self._local, "stack", None)
+        if stack is None:
+            stack = self._local.stack = []
+        return stack
 
     # -- metrics registry ---------------------------------------------------
 

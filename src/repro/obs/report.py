@@ -9,7 +9,9 @@ previously could not extract from a run: the per-block coverage table
 (paper Fig. 2), the staleness histogram (semi-async), the up/down
 traffic breakdown per assigned width, per-capacity-class participation,
 jit-recompile counts, and wall-time summaries of the instrumented host
-stages.  ``--trace`` additionally writes the Perfetto/Chrome
+stages (every wall span of :data:`repro.obs.SPANS` through its
+``<name>_s`` histogram, with its self time: its total less that of the
+spans whose ``parent`` it is).  ``--trace`` additionally writes the Perfetto/Chrome
 ``trace_event`` export of the span stream.
 """
 
@@ -165,11 +167,18 @@ def render_report(events: List[Dict[str, Any]]) -> str:
     stage_names = sorted(k for k in hists if k.endswith("_s"))
     if not stage_names:
         lines.append("  (none recorded)")
+    # self time: a span's total less the wall spans it directly encloses
+    children: Dict[str, float] = {}
+    for e in events:
+        if e.get("type") == "span" and e.get("parent"):
+            children[e["parent"]] = (children.get(e["parent"], 0.0)
+                                     + e["t1"] - e["t0"])
     for k in stage_names:
         v = hists[k]
         lines.append(f"  {k[:-2]:>24}: n={len(v):4d}  total="
-                     f"{sum(v):8.3f}s  mean={sum(v) / len(v):8.4f}s  "
-                     f"max={max(v):8.4f}s")
+                     f"{sum(v):8.3f}s  self="
+                     f"{sum(v) - children.get(k[:-2], 0.0):8.3f}s  "
+                     f"mean={sum(v) / len(v):8.4f}s  max={max(v):8.4f}s")
 
     ckpt = counters.get("checkpoint.bytes")
     if ckpt:

@@ -11,7 +11,8 @@ Every line of ``events.jsonl`` is one JSON object with a ``type``:
     "t0": num, "t1": num >= t0, "attrs": {...}}`` — an interval on the
     virtual clock (simulated seconds: per-client train/upload) or the
     wall clock (perf_counter seconds: merges, staging, device steps,
-    checkpoint writes).
+    checkpoint writes).  Wall spans also carry ``"parent": str|null``,
+    the enclosing wall span on the same thread.
 ``event``
     ``{"type": "event", "name": str, "clock": ..., "t": num,
     "attrs": {...}}`` — a point on either clock.
@@ -59,6 +60,8 @@ def validate_event(obj: Dict[str, Any], i: int = 0) -> None:
             _fail(i, f"span ends before it starts ({obj['t0']}..{obj['t1']})")
         if not isinstance(obj.get("attrs"), dict):
             _fail(i, "span attrs must be an object")
+        if not isinstance(obj.get("parent"), (str, type(None))):
+            _fail(i, "span parent must be a name or null")
     elif t == "event":
         if not isinstance(obj.get("name"), str):
             _fail(i, "event without a string name")
