@@ -5,11 +5,15 @@ eager scatters — O(K) dispatches per layer, and the server state can
 never leave one device.  This backend makes the paper's block-wise merge
 (Eq. 5) the mesh-native ``masked_block_mean`` path end to end:
 
-  1. *prep* (host, numpy): every client result is turned into a dense
-     zero-padded contribution + mask (``scatter_contributions_host``) —
-     the contract from ``repro.core.aggregation``.  Staleness weights
-     (semi-async) are blended here, client-side, exactly as the host
-     rule does: ``w * update + (1 - w) * global``.  When the
+  1. *prep*: every client result is turned into a dense zero-padded
+     contribution + mask — the contract of
+     ``repro.core.aggregation.scatter_contributions_host``.  Staleness
+     weights (semi-async) are blended on the host, in numpy, exactly as
+     the host rule does: ``w * update + (1 - w) * global``.  The scatter
+     itself runs on the device: one compiled call per client uploads its
+     blocks and ids and writes its ``scatter_contribution`` of every
+     tensor into its row of a zeroed, donated stack (Heroes; the dense,
+     HeteroFL and Flanc rules stack numpy on the host).  When the
      mesh-sharded cohort trainer hands over *device-resident* stacks
      (:class:`CohortStack` / :class:`CohortSlice`) and no weights are in
      play, prep stays on device instead: rows are gathered from the
@@ -36,6 +40,7 @@ fold, so multi-device parity is to float tolerance.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Dict, FrozenSet, List, Optional
 
 import jax
@@ -193,6 +198,36 @@ def _fact_1d(stacked):
     }
 
 
+@functools.partial(jax.jit, static_argnames="layout")
+def _zero_contributions(layout):
+    """Zeroed ``{name: {dense, mask}}`` stacks for ``layout``, a tuple of
+    ``(name, (k_pad, num_blocks, R, O), dtype)``."""
+    return {name: {"dense": jnp.zeros(shape, dtype),
+                   "mask": jnp.zeros(shape[:2], jnp.float32)}
+            for name, shape, dtype in layout}
+
+
+@functools.partial(jax.jit, donate_argnums=0)
+def _scatter_client(contrib, row, blocks, ids):
+    """One client's Eq. 5 contributions, every tensor in one call:
+    ``scatter_contribution(blocks[name], ids[name])`` written in place
+    into row ``row`` of the donated stacks ``contrib``.  Its shapes
+    depend only on the client's width, so a round builds at most one
+    program per width."""
+    out = {}
+    for name, t in contrib.items():
+        dense, mask = aggregation.scatter_contribution(
+            blocks[name].astype(t["dense"].dtype), ids[name],
+            t["dense"].shape[1])
+        out[name] = {
+            "dense": jax.lax.dynamic_update_index_in_dim(
+                t["dense"], dense, row, 0),
+            "mask": jax.lax.dynamic_update_index_in_dim(
+                t["mask"], mask, row, 0),
+        }
+    return out
+
+
 @jax.jit
 def _mean_1d(stacked):
     """Plain mean over the client axis, leaf-wise (FedAvg/ADP)."""
@@ -241,22 +276,24 @@ class CollectiveMerger:
         # telemetry recorder (rebound by the engine runner); merge
         # *latency* is spanned at the loop level ("aggregate.merge"),
         # the merger spans its two stages inside it ("merge.prep",
-        # "merge.compiled") and counts per-rule compiled-merge calls and
-        # the host bytes each call ships ("merge.h2d_bytes")
+        # "merge.compiled") and counts per-rule compiled-merge calls, the
+        # host bytes a merge ships ("merge.h2d_bytes") and the clients
+        # scattered on the device ("merge.device_scatter_clients")
         self.obs = NOOP
 
     def _count(self, rule: str) -> None:
         if self.obs.enabled:
             self.obs.counter_add("aggregate.collective_calls", rule=rule)
 
-    def _compiled(self, finish, stacked, *args):
+    def _compiled(self, finish, stacked, *args, uploaded: int = 0):
         """``finish(stacked, *args)`` under ``merge.compiled``, adding the
-        bytes of the numpy leaves it hands to the device (the host-prep
-        contributions; device-resident leaves add 0) to
-        ``merge.h2d_bytes`` and to the span."""
+        bytes the merge sends to the device — the numpy leaves handed to
+        ``finish`` (device-resident leaves add 0) plus ``uploaded``, the
+        prep's own uploads — to ``merge.h2d_bytes`` and to the span."""
         obs = self.obs
-        nbytes = (sum(v.nbytes for v in jax.tree_util.tree_leaves(stacked)
-                      if isinstance(v, np.ndarray)) if obs.enabled else 0)
+        nbytes = (uploaded + sum(
+            v.nbytes for v in jax.tree_util.tree_leaves(stacked)
+            if isinstance(v, np.ndarray)) if obs.enabled else 0)
         obs.counter_add("merge.h2d_bytes", nbytes)
         with obs.wall_span("merge.compiled", h2d_bytes=nbytes):
             return finish(stacked, *args)
@@ -480,54 +517,73 @@ class CollectiveMerger:
         self._count("factorized")
         k = len(results)
         k_pad = flsh.pad_cohort(k, self.mesh)
-        with self.obs.wall_span("merge.prep", clients=k):
-            stacked, shard_names = self._stack_factorized(
+        self.obs.counter_add("merge.device_scatter_clients", k)
+        with self.obs.wall_span("merge.prep", clients=k, device_scatter=k):
+            stacked, shard_names, uploaded = self._stack_factorized(
                 prev_params, specs, results, assigns, weights, k_pad)
-        return self._compiled(self._finish_fact, stacked, k, shard_names)
+        return self._compiled(self._finish_fact, stacked, k, shard_names,
+                              uploaded=uploaded)
 
     def _stack_factorized(self, prev_params, specs, results, assigns,
                           weights, k_pad: int):
-        """``merge_factorized``'s inputs: ``(stacked, shard_names)``."""
+        """``merge_factorized``'s inputs: ``(stacked, shard_names,
+        uploaded)``, ``uploaded`` the bytes of client blocks and ids the
+        prep sends to the device.  Each client's (blended) blocks go to
+        ``_scatter_client`` in results order; the bases stay a numpy
+        stack."""
         if weights is None:
             groups = _device_groups(results)
             if groups is not None:
                 return self._stack_factorized_device(
-                    prev_params, specs, groups, k_pad, assigns)
+                    prev_params, specs, groups, k_pad, assigns) + (0,)
         results = _host_results(results)
-        stacked: Dict[str, Dict[str, Any]] = {}
-        for name, spec in specs.items():
-            ids_key = "hidden_ids" if spec.mode == "square" else "anchored_ids"
-            prev_c = prev_params[name]["coeff"]
-            prev_c_np = prev_b_np = None
-            bases, blocks, ids = [], [], []
-            for n, r in results.items():
+        prev_np: Dict[str, Any] = {}
+        bases: Dict[str, List[np.ndarray]] = {name: [] for name in specs}
+        contrib = None
+        uploaded = 0
+        for j, (n, r) in enumerate(results.items()):
+            w = _weight_of(weights, n)
+            blocks, ids = {}, {}
+            for name, spec in specs.items():
+                key = "hidden_ids" if spec.mode == "square" else "anchored_ids"
+                i = np.asarray(assigns[n][key], np.int32)
                 b = np.asarray(r.params[name]["basis"])
-                c = np.asarray(r.params[name]["coeff"])
-                i = np.asarray(assigns[n][ids_key])
-                w = _weight_of(weights, n)
+                c = r.params[name]["coeff"]
                 if w is not None:
-                    if prev_c_np is None:
-                        prev_c_np = np.asarray(prev_c)
-                        prev_b_np = np.asarray(prev_params[name]["basis"])
-                    b = _np_blend(b, w, prev_b_np)
-                    c = _np_blend(c, w, prev_c_np[i])
-                bases.append(b)
-                blocks.append(c)
-                ids.append(i)
-            dense, mask = aggregation.scatter_contributions_host(
-                blocks, ids, num_blocks=prev_c.shape[0])
-            stacked[name] = {
-                "bases": _pad_rows(np.stack(bases), k_pad),
-                "dense": _pad_rows(dense, k_pad),
-                "mask": _pad_rows(mask, k_pad),
-                "prev": prev_c,
-            }
+                    if name not in prev_np:
+                        prev_np[name] = (
+                            np.asarray(prev_params[name]["basis"]),
+                            np.asarray(prev_params[name]["coeff"]))
+                    b = _np_blend(b, w, prev_np[name][0])
+                    c = _np_blend(c, w, prev_np[name][1][i])
+                bases[name].append(b)
+                blocks[name] = c
+                ids[name] = i
+            if contrib is None:
+                contrib = _zero_contributions(tuple(
+                    (name, (k_pad, prev_params[name]["coeff"].shape[0])
+                     + tuple(blk.shape[-2:]), np.dtype(blk.dtype))
+                    for name, blk in blocks.items()))
+            contrib = _scatter_client(contrib, np.int32(j), blocks, ids)
+            uploaded += sum(v.nbytes for v in (*blocks.values(),
+                                               *ids.values())
+                            if isinstance(v, np.ndarray))
+        if self.mesh is not None:
+            contrib = jax.device_put(contrib, jax.sharding.NamedSharding(
+                self.mesh, flsh.contribution_spec()))
+        stacked = {
+            name: {"bases": _pad_rows(np.stack(bases[name]), k_pad),
+                   "dense": contrib[name]["dense"],
+                   "mask": contrib[name]["mask"],
+                   "prev": prev_params[name]["coeff"]}
+            for name in specs
+        }
         shard_names: FrozenSet[str] = frozenset()
         if self.shard_blocks:
             shard_names = frozenset(
                 n for n, t in stacked.items()
                 if flsh.can_shard_blocks(t["prev"].shape[0], self.mesh))
-        return stacked, shard_names
+        return stacked, shard_names, uploaded
 
     def merge_dense_mean(self, prev_params, results, weights=None):
         """FedAvg/ADP: plain parameter mean over the cohort."""

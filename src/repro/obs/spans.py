@@ -40,7 +40,8 @@ SPANS: Dict[str, str] = {
     "aggregate.merge":
         "the server merge of one round (aggregator.aggregate)",
     "merge.prep":
-        "collective merge: contributions blended, scattered and stacked",
+        "collective merge: contributions blended, scattered and stacked "
+        "(counter merge.device_scatter_clients)",
     "merge.compiled":
         "collective merge: the compiled call, with its host-to-device "
         "copies (counter merge.h2d_bytes)",

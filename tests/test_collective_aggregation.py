@@ -223,6 +223,105 @@ def test_collective_merger_bf16_roundtrip():
     assert out["l"]["coeff"].dtype == jnp.bfloat16
 
 
+def _tiny_round(rng, prev):
+    """Three clients of widths 1, 2 and 3 (1, 4 and 9 hidden blocks) on
+    a tiny two-tensor factorized model: the width-3 client trains block 2
+    twice, nobody trains block 8."""
+    from repro.fl.client import ClientResult
+
+    ids = [np.array([4]), np.array([0, 1, 5, 6]),
+           np.array([0, 1, 2, 2, 3, 4, 5, 6, 7])]
+    results, assigns = {}, {}
+    for n, i in zip((7, 2, 5), ids):
+        results[n] = ClientResult(
+            {name: {"basis": rng.normal(size=t["basis"].shape)
+                    .astype(np.float32),
+                    "coeff": rng.normal(size=(len(i),) + t["coeff"].shape[1:])
+                    .astype(np.float32)}
+             for name, t in prev.items()}, {}, 0.0, 0.0)
+        assigns[n] = {"hidden_ids": i}
+    return results, assigns
+
+
+def _tiny_model(rng):
+    class Square:
+        mode = "square"
+
+    shapes = {"a": ((2, 3, 5), (9, 3, 4)), "b": ((3, 2, 6), (9, 2, 6))}
+    prev = {name: {"basis": jnp.asarray(rng.normal(size=b)
+                                        .astype(np.float32)),
+                   "coeff": jnp.asarray(rng.normal(size=c)
+                                        .astype(np.float32))}
+            for name, (b, c) in shapes.items()}
+    return prev, {name: Square() for name in shapes}
+
+
+def test_device_scatter_stacks_match_the_host_contract():
+    """The merger's per-client device scatter lays out the same dense
+    contributions and masks as ``scatter_contributions_host``, in results
+    order: duplicate ids accumulate and each counts in the mask."""
+    from repro.core import scatter_contributions_host
+    from repro.fl.engine.collective import CollectiveMerger
+
+    rng = np.random.default_rng(11)
+    prev, specs = _tiny_model(rng)
+    results, assigns = _tiny_round(rng, prev)
+    stacked, _, uploaded = CollectiveMerger()._stack_factorized(
+        prev, specs, results, assigns, None, len(results))
+    for name in specs:
+        dense, mask = scatter_contributions_host(
+            [r.params[name]["coeff"] for r in results.values()],
+            [a["hidden_ids"] for a in assigns.values()], 9)
+        assert isinstance(stacked[name]["dense"], jax.Array)
+        np.testing.assert_array_equal(np.asarray(stacked[name]["dense"]),
+                                      dense)
+        np.testing.assert_array_equal(np.asarray(stacked[name]["mask"]),
+                                      mask)
+        assert mask[2, 2] == 2.0
+    assert uploaded == sum(r.params[n]["coeff"].nbytes
+                           + 4 * len(assigns[c]["hidden_ids"])
+                           for c, r in results.items() for n in specs)
+
+
+def test_device_scatter_merge_matches_host_aggregate_bitwise():
+    """Mixed widths, a duplicate id and an untrained block: the merge
+    equals the host ``aggregate_factorized`` bitwise on one device, and
+    the untrained block keeps ``prev`` bitwise."""
+    from repro.core import aggregate_factorized
+    from repro.fl.engine.collective import CollectiveMerger
+
+    rng = np.random.default_rng(12)
+    prev, specs = _tiny_model(rng)
+    results, assigns = _tiny_round(rng, prev)
+    host = aggregate_factorized(
+        prev, [r.params for r in results.values()],
+        [a["hidden_ids"] for a in assigns.values()])
+    merged = CollectiveMerger().merge_factorized(prev, specs, results,
+                                                 assigns)
+    _leaves_equal(host, merged, exact=SINGLE_DEVICE)
+    for name in specs:
+        np.testing.assert_array_equal(np.asarray(merged[name]["coeff"][8]),
+                                      np.asarray(prev[name]["coeff"][8]))
+
+
+def test_device_scatter_builds_no_program_for_widths_seen():
+    """A second round with the widths of the first builds no new
+    per-client scatter program."""
+    from repro.fl.engine import collective
+
+    rng = np.random.default_rng(13)
+    prev, specs = _tiny_model(rng)
+    merger = collective.CollectiveMerger()
+    for rnd in range(2):
+        results, assigns = _tiny_round(rng, prev)
+        prev = merger.merge_factorized(prev, specs, results, assigns)
+        jax.block_until_ready(prev)
+        if rnd == 0:
+            built = collective._scatter_client._cache_size()
+    assert built >= 3
+    assert collective._scatter_client._cache_size() == built
+
+
 # ---------------------------------------------------------------------------
 # SPMD: real multi-device meshes (subprocess so XLA_FLAGS precede jax init)
 # ---------------------------------------------------------------------------

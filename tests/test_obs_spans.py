@@ -4,9 +4,11 @@ host<->device byte counters (``repro.obs.spans``).
 A one-round Heroes run with ``telemetry="memory"`` under
 ``jax.profiler.trace``: every span of the round shows up in the xplane
 host plane with the nesting the recorder's ``parent`` fields give; the
-merge's ``merge.h2d_bytes`` equals the shape formula of its stacked
-contributions and the trainer's ``trainer.d2h_bytes`` the bytes of the
-params it returned; with telemetry off no span is opened at all.
+merge's ``merge.h2d_bytes`` equals the bytes of the client blocks, ids
+and bases it uploads, its ``merge.prep`` counts every client as
+scattered on the device (``device_scatter``), the trainer's
+``trainer.d2h_bytes`` equals the bytes of the params it returned; with
+telemetry off no span is opened at all.
 """
 
 import re
@@ -135,16 +137,40 @@ def test_byte_counters_match_shapes(image_setup):
     assert counters["trainer.d2h_bytes"] == pulled
     assert sum(e["attrs"]["d2h_bytes"]
                for e in sink.spans("trainer.pull")) == pulled
-    # k zero-padded full coefficient tensors, k bases, k float32 masks
-    full = jax.eval_shape(lambda: model.init_factorized(
-        jax.random.PRNGKey(0)))
-    per_client = sum(np.prod(t[k].shape) * t[k].dtype.itemsize
-                     for t in full.values() for k in ("coeff", "basis"))
-    masks = sum(t["coeff"].shape[0] * 4 for t in full.values())
-    want = len(results) * (per_client + masks)
-    assert counters["merge.h2d_bytes"] == want
+    assert counters["merge.h2d_bytes"] == _merge_upload_bytes(
+        eng, results, assigns)
     (compiled,) = sink.spans("merge.compiled")
-    assert compiled["attrs"]["h2d_bytes"] == want
+    assert compiled["attrs"]["h2d_bytes"] == counters["merge.h2d_bytes"]
+    eng.close()
+
+
+def _merge_upload_bytes(eng, results, assigns):
+    """What the Heroes merge sends to the device: each client's trained
+    coefficient blocks and its int32 block ids, per tensor, plus the
+    stacked bases; the dense contributions are made on the device."""
+    total = 0
+    for n, r in results.items():
+        for name, spec in eng.model.specs.items():
+            key = "hidden_ids" if spec.mode == "square" else "anchored_ids"
+            total += r.params[name]["coeff"].nbytes
+            total += 4 * len(assigns[n][key])
+            total += r.params[name]["basis"].nbytes
+    return total
+
+
+def test_merge_prep_counts_clients_scattered_on_the_device(image_setup):
+    model, px, py, test = image_setup
+    eng = build_runner("heroes", model, px, py, test,
+                       cfg=_cfg(telemetry="memory", clients_per_round=3))
+    for clients in ([0, 4, 7], [1, 2]):
+        state, assigns = eng.assignment.assign(eng.state, clients)
+        results = eng.trainer.train_all(state, assigns)
+        eng.aggregator.aggregate(state, results, assigns)
+    preps = eng.obs.sinks[0].spans("merge.prep")
+    assert [e["attrs"]["clients"] for e in preps] == [3, 2]
+    for e in preps:
+        assert e["attrs"]["device_scatter"] == e["attrs"]["clients"]
+    assert eng.obs.counters["merge.device_scatter_clients"] == 5
     eng.close()
 
 
