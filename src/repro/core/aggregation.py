@@ -44,6 +44,13 @@ import numpy as np
 Array = jax.Array
 
 
+def _per_block(v: Array, like: Array) -> Array:
+    """A per-block vector ``(num_blocks,)`` shaped to broadcast over a
+    coefficient ``like`` of any rank (``(P^2, R, O)``, or an expert
+    bank's ``(P^2, E, R, O)``)."""
+    return v.reshape(v.shape + (1,) * (like.ndim - 1))
+
+
 def ordered_sum(stacked: Array) -> Array:
     """Sum over the leading axis with fixed left-to-right association.
 
@@ -111,10 +118,10 @@ def aggregate_coefficient(
             blocks = w * blocks + (1.0 - w) * global_coeff[ids]
         acc = acc.at[ids].add(blocks)
         cnt = cnt.at[ids].add(1.0)
-    trained = cnt > 0
-    denom = jnp.where(trained, cnt, 1.0)[:, None, None].astype(acc.dtype)
+    trained = _per_block(cnt > 0, acc)
+    denom = _per_block(jnp.where(cnt > 0, cnt, 1.0), acc).astype(acc.dtype)
     mean = acc / denom
-    return jnp.where(trained[:, None, None], mean, global_coeff)
+    return jnp.where(trained, mean, global_coeff)
 
 
 def aggregate_factorized(
@@ -150,10 +157,8 @@ def scatter_contribution(
     host path's ``at[ids].add``): the dense row receives the sum of the
     duplicate rows and the mask counts each occurrence.
     """
-    r, o = updated_blocks.shape[-2:]
-    dense = jnp.zeros((num_blocks, r, o), updated_blocks.dtype).at[block_ids].add(
-        updated_blocks
-    )
+    dense = jnp.zeros((num_blocks,) + updated_blocks.shape[1:],
+                      updated_blocks.dtype).at[block_ids].add(updated_blocks)
     mask = jnp.zeros((num_blocks,), jnp.float32).at[block_ids].add(1.0)
     return dense, mask
 
@@ -194,8 +199,7 @@ def scatter_contributions_host(
             client_blocks, jnp.asarray(client_block_ids), num_blocks)
     k = len(client_blocks)
     first = np.asarray(client_blocks[0])
-    r, o = first.shape[-2:]
-    dense = np.zeros((k, num_blocks, r, o),
+    dense = np.zeros((k, num_blocks) + first.shape[1:],
                      dtype or first.dtype)
     mask = np.zeros((k, num_blocks), np.float32)
     for j, (blocks, ids) in enumerate(zip(client_blocks, client_block_ids)):
@@ -214,9 +218,9 @@ def masked_block_mean(
     """
     total = jax.lax.psum(dense_contrib, axis_name)
     count = jax.lax.psum(mask, axis_name)
-    trained = count > 0
-    denom = jnp.where(trained, count, 1.0)[:, None, None].astype(total.dtype)
-    return jnp.where(trained[:, None, None], total / denom, prev_coeff)
+    trained = _per_block(count > 0, total)
+    denom = _per_block(jnp.where(count > 0, count, 1.0), total)
+    return jnp.where(trained, total / denom.astype(total.dtype), prev_coeff)
 
 
 def masked_block_merge(
@@ -240,7 +244,7 @@ def masked_block_merge(
     if axis_name is not None:
         total = jax.lax.psum(total, axis_name)
         count = jax.lax.psum(count, axis_name)
-    trained = count > 0
-    denom = jnp.where(trained, count, 1.0)[:, None, None].astype(total.dtype)
-    mean = total / denom
-    return jnp.where(trained[:, None, None], mean, prev_coeff)
+    trained = _per_block(count > 0, total)
+    denom = _per_block(jnp.where(count > 0, count, 1.0), total)
+    mean = total / denom.astype(total.dtype)
+    return jnp.where(trained, mean, prev_coeff)

@@ -57,6 +57,11 @@ class CompositionSpec:
                    p blocks;
         "grow_in"  output-anchored (classifier): (pI x O), p blocks.
         The anchored modes are the Flanc treatment of boundary layers.
+      experts: ``E`` > 1 makes the spec an expert bank: ``E`` weights of
+        the same shape, each with its own basis and its own blocks —
+        basis ``(E, I, R)``, coefficient ``(P^2, E, R, O)`` (blocks stay
+        on the leading axis, so block selection and the Eq. 5 merge are
+        those of a single weight), weight ``(E, pI, pO)``.  Dense only.
     """
 
     max_width: int
@@ -65,6 +70,11 @@ class CompositionSpec:
     base_out: int
     ksq: int = 1
     mode: str = "square"
+    experts: int = 1
+
+    def __post_init__(self):
+        if self.experts > 1 and self.ksq != 1:
+            raise ValueError("an expert bank is dense: ksq must be 1")
 
     @property
     def num_blocks(self) -> int:
@@ -76,26 +86,35 @@ class CompositionSpec:
             raise ValueError(f"width {p} outside [1, {self.max_width}]")
         return p * p if self.mode == "square" else p
 
-    def basis_shape(self) -> Tuple[int, int, int]:
-        return (self.ksq, self.base_in, self.rank)
+    @property
+    def lead(self) -> int:
+        """Leading axis of basis and weight: the expert count of a bank,
+        the spatial taps of a convolution, else 1."""
+        return self.experts if self.experts > 1 else self.ksq
 
-    def coefficient_shape(self) -> Tuple[int, int, int]:
+    def basis_shape(self) -> Tuple[int, int, int]:
+        return (self.lead, self.base_in, self.rank)
+
+    def coefficient_shape(self) -> Tuple[int, ...]:
+        if self.experts > 1:
+            return (self.num_blocks, self.experts, self.rank, self.base_out)
         return (self.num_blocks, self.rank, self.base_out)
 
     def weight_shape(self, p: int) -> Tuple[int, int, int]:
         pi = p if self.mode in ("square", "grow_in") else 1
         po = p if self.mode in ("square", "grow_out") else 1
-        return (self.ksq, pi * self.base_in, po * self.base_out)
+        return (self.lead, pi * self.base_in, po * self.base_out)
 
     def params_factorized(self, p: int) -> int:
         """Parameter count shipped to a width-``p`` client (basis + blocks)."""
-        basis = self.ksq * self.base_in * self.rank
-        coeff = self.blocks_for_width(p) * self.rank * self.base_out
+        basis = self.lead * self.base_in * self.rank
+        coeff = (self.experts * self.blocks_for_width(p) * self.rank
+                 * self.base_out)
         return basis + coeff
 
     def params_materialized(self, p: int) -> int:
-        _, pi, po = self.weight_shape(p)
-        return self.ksq * pi * po
+        lead, pi, po = self.weight_shape(p)
+        return lead * pi * po
 
 
 def init_factors(
@@ -179,21 +198,32 @@ def compose(basis: Array, reduced_coeff: Array, p: int, spec: CompositionSpec,
     Returns:
       the ``spec.weight_shape(p)`` weight.  For "square" the intermediate
       ``(ksq, I, p^2·O)`` tensor is viewed as ``(ksq, I, p, p·O)`` and the
-      first ``p`` axis merges with ``I`` (the paper's reshape).
+      first ``p`` axis merges with ``I`` (the paper's reshape).  An expert
+      bank (basis ``(E, I, R)``, blocks ``(m, E, R, O)``) composes every
+      expert in one call, the expert axis in place of ``ksq``.
     """
     m = spec.blocks_for_width(p)
     if reduced_coeff.shape[0] != m:
         raise ValueError(f"expected {m} blocks, got {reduced_coeff.shape[0]}")
     if backend is None:
         backend = "pallas" if _pallas_compose_default() else "einsum"
+    bank = spec.experts > 1
     if backend == "pallas":
         from repro.kernels.compose import compose_pallas
 
-        flat = compose_pallas(basis, reduced_coeff)  # (ksq, I, m*O)
+        if bank:
+            # the kernel's batched form, one expert per leading row:
+            # (E, 1, I, R) x (E, m, R, O) -> (E, 1, I, m*O)
+            flat = compose_pallas(basis[:, None],
+                                  jnp.swapaxes(reduced_coeff, 0, 1))[:, 0]
+        else:
+            flat = compose_pallas(basis, reduced_coeff)  # (ksq, I, m*O)
         inter = flat.reshape(flat.shape[0], flat.shape[1], m, -1)
     elif backend == "einsum":
-        # (ksq, I, R) x (m, R, O) -> (ksq, I, m, O)
-        inter = jnp.einsum("kir,mro->kimo", basis, reduced_coeff)
+        # (ksq, I, R) x (m, R, O) -> (ksq, I, m, O); a bank's expert axis
+        # takes ksq's place
+        inter = (jnp.einsum("eir,mero->eimo", basis, reduced_coeff) if bank
+                 else jnp.einsum("kir,mro->kimo", basis, reduced_coeff))
     else:
         raise ValueError(f"unknown compose backend {backend!r}")
     ksq, I, _, O = inter.shape
@@ -208,9 +238,10 @@ def compose(basis: Array, reduced_coeff: Array, p: int, spec: CompositionSpec,
 
 
 def compose_flops(p: int, spec: CompositionSpec) -> int:
-    """MACs*2 for the compose contraction at width p."""
+    """MACs*2 for the compose contraction at width p (every expert of a
+    bank)."""
     m = spec.blocks_for_width(p)
-    return 2 * spec.ksq * spec.base_in * spec.rank * m * spec.base_out
+    return 2 * spec.lead * spec.base_in * spec.rank * m * spec.base_out
 
 
 # ---------------------------------------------------------------------------
@@ -266,6 +297,9 @@ def apply_factors(x: Array, basis: Array, reduced_coeff: Array, p: int,
       exactly what ``x @ compose(...)`` / ``conv(x, compose(...))``
       returns, up to float re-association.
     """
+    if spec.experts > 1:
+        raise ValueError("an expert bank is applied composed, by a grouped "
+                         "matmul over its routed rows")
     if mode == "dense":
         if spec.ksq != 1:
             raise ValueError("dense apply requires ksq == 1")

@@ -50,22 +50,46 @@ def _jitted_fns(model: FLModelDef, width: int, factorized: bool,
     # per-process measurement) joins the key so two configs with
     # different cost-model overrides never share impl choices.
 
+    # Every program also returns the forward's device-side counts
+    # (``FLModelDef.forward_stats``; an empty dict, so no output at all,
+    # for a model that counts nothing).
+
     def loss_fn(params, batch):
         w = (model.prepare_weights(params, width, batch, forward_impl,
                                    calibration)
              if factorized else {k: v for k, v in params.items()})
-        logits = model.forward(w, width, batch)
-        return _ce(logits, batch["labels"])
+        logits, stats = model.forward_with_stats(w, width, batch)
+        return _ce(logits, batch["labels"]), stats
 
-    grad_fn = jax.jit(jax.grad(loss_fn))
+    grad_fn = jax.jit(jax.grad(loss_fn, has_aux=True))
     loss_jit = jax.jit(loss_fn)
 
     @jax.jit
     def sgd_step(params, batch, lr):
-        g = jax.grad(loss_fn)(params, batch)
-        return jax.tree_util.tree_map(lambda p, gg: p - lr * gg, params, g)
+        g, stats = jax.grad(loss_fn, has_aux=True)(params, batch)
+        return (jax.tree_util.tree_map(lambda p, gg: p - lr * gg, params, g),
+                stats)
 
     return loss_jit, grad_fn, sgd_step
+
+
+def _sum_stats(forward: list, backward: list) -> Dict[str, int]:
+    """A client-round's counts from the per-program ``stats`` of its
+    forward-only (``forward``) and forward-and-backward (``backward``)
+    calls, all read back in one ``device_get``: each key summed over
+    every call, and again as ``backward.<key>`` over the second kind
+    alone; a vector key (a per-expert load) is summed entrywise and
+    reported as its largest entry, ``<key>_max``."""
+    fwd, bwd = jax.device_get((forward, backward))
+    sums: Dict[str, np.ndarray] = {}
+    for part, prefixes in ((fwd, ("",)), (bwd, ("", "backward."))):
+        for stats in part:
+            for k, v in stats.items():
+                for pre in prefixes:
+                    sums[pre + k] = sums.get(pre + k, 0) + np.asarray(
+                        v, np.int64)
+    return {(k + "_max" if np.ndim(v) else k): int(np.max(v))
+            for k, v in sums.items()}
 
 
 @dataclasses.dataclass
@@ -74,6 +98,9 @@ class ClientResult:
     estimates: Dict[str, float]
     loss_before: float
     loss_after: float
+    # the forward's device-side counts over the client-round (see
+    # ``_sum_stats``); read back only when telemetry is on
+    stats: Dict[str, int] = dataclasses.field(default_factory=dict)
 
     def host_params(self) -> Any:
         """Params as a host pytree.
@@ -110,7 +137,9 @@ def local_train(
     it is cheaper (``calibration`` carries an FLConfig override; None =
     the per-process measurement).  Ignored when ``factorized=False``.
     ``obs`` records the ``trainer.sgd``, ``trainer.loss`` and
-    ``trainer.estimate`` wall spans (nothing with the default no-op).
+    ``trainer.estimate`` wall spans (nothing with the default no-op);
+    with it on, the forward's counts of every program the client ran
+    come back in ``ClientResult.stats``, read after the estimates.
     """
     loss_jit, grad_fn, sgd_step = _jitted_fns(model, width, factorized,
                                               forward_impl, calibration)
@@ -118,18 +147,27 @@ def local_train(
     params = params0
     n = len(y)
     first_batch = None
+    fwd_stats, bwd_stats = [], []
     with obs.wall_span("trainer.sgd"):
         for _ in range(max(tau, 1)):
             idx = rng.integers(0, n, min(batch_size, n))
             batch = data_batch(model, x, y, idx)
             if first_batch is None:
                 first_batch = batch
-            params = sgd_step(params, batch, lr)
+            params, stats = sgd_step(params, batch, lr)
+            bwd_stats.append(stats)
+
+    def grad(p, b):
+        g, stats = grad_fn(p, b)
+        bwd_stats.append(stats)
+        return g
 
     est = {}
     with obs.wall_span("trainer.loss"):
-        loss_b = float(loss_jit(params0, first_batch))
-        loss_a = float(loss_jit(params, first_batch))
+        loss_b, stats_b = loss_jit(params0, first_batch)
+        loss_a, stats_a = loss_jit(params, first_batch)
+        fwd_stats += [stats_b, stats_a]
+        loss_b, loss_a = float(loss_b), float(loss_a)
     if estimate:
         with obs.wall_span("trainer.estimate"):
             batches = [
@@ -137,8 +175,8 @@ def local_train(
                            rng.integers(0, n, min(batch_size, n)))
                 for _ in range(3)
             ]
-            est = estimator.client_estimates(
-                lambda p, b: grad_fn(p, b), params0, params, batches
-            )
+            est = estimator.client_estimates(grad, params0, params, batches)
             est = {k: float(v) for k, v in est.items()}
-    return ClientResult(params, est, loss_b, loss_a)
+    counted = (_sum_stats(fwd_stats, bwd_stats)
+               if obs.enabled and model.forward_stats is not None else {})
+    return ClientResult(params, est, loss_b, loss_a, counted)

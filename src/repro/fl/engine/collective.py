@@ -201,7 +201,7 @@ def _fact_1d(stacked):
 @functools.partial(jax.jit, static_argnames="layout")
 def _zero_contributions(layout):
     """Zeroed ``{name: {dense, mask}}`` stacks for ``layout``, a tuple of
-    ``(name, (k_pad, num_blocks, R, O), dtype)``."""
+    ``(name, (k_pad, num_blocks, *block_shape), dtype)``."""
     return {name: {"dense": jnp.zeros(shape, dtype),
                    "mask": jnp.zeros(shape[:2], jnp.float32)}
             for name, shape, dtype in layout}
@@ -562,7 +562,7 @@ class CollectiveMerger:
             if contrib is None:
                 contrib = _zero_contributions(tuple(
                     (name, (k_pad, prev_params[name]["coeff"].shape[0])
-                     + tuple(blk.shape[-2:]), np.dtype(blk.dtype))
+                     + tuple(blk.shape[1:]), np.dtype(blk.dtype))
                     for name, blk in blocks.items()))
             contrib = _scatter_client(contrib, np.int32(j), blocks, ids)
             uploaded += sum(v.nbytes for v in (*blocks.values(),

@@ -119,7 +119,8 @@ class SequentialTrainer(LocalTrainer):
                     eng.cfg.forward_impl, cal)
                 before = _cache_size(sgd_step)
             with obs.wall_span("trainer.local_train", client=int(n),
-                               width=int(a["width"]), tau=int(a["tau"])):
+                               width=int(a["width"]),
+                               tau=int(a["tau"])) as span:
                 res = client_lib.local_train(
                     eng.model, params, a["width"], a["tau"],
                     eng.parts_x[n], eng.parts_y[n], eng.cfg.lr,
@@ -129,6 +130,13 @@ class SequentialTrainer(LocalTrainer):
                     forward_impl=eng.cfg.forward_impl,
                     calibration=cal, obs=obs,
                 )
+                if res.stats:
+                    # the forward's counts (the expert model's moe.*) ride
+                    # on the span and add to the counters of their names
+                    span.attrs.update(res.stats)
+                    for k, v in res.stats.items():
+                        if not k.startswith("backward."):
+                            obs.counter_add(k, v)
             if obs.enabled:
                 _count_recompiles(obs, sgd_step, before,
                                   trainer="sequential",

@@ -105,7 +105,7 @@ class LayerHint:
         return self.apps_per_sample
 
 
-LAYER_KINDS = ("dense", "conv", "embed")
+LAYER_KINDS = ("dense", "conv", "embed", "experts")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -124,7 +124,11 @@ class ComposedLayer:
              inputs ``(B, T, pI)`` work unchanged);
       conv   NHWC SAME conv, ``ksq`` taps, optional stride;
       embed  token gather; the rank path gathers R-length basis rows and
-             finishes with the coefficient contraction.
+             finishes with the coefficient contraction;
+      experts an expert bank (``spec.experts > 1``): the model applies
+             its composed ``(E, pI, pO)`` weights (``materialized``) by a
+             grouped matmul over rows sorted by expert; ``apply`` refuses.
+             Always composed (hint ``rank_capable=False``).
     """
 
     name: str
@@ -143,12 +147,19 @@ class ComposedLayer:
         if self.kind == "embed" and self.spec.mode != "grow_out":
             raise ValueError(f"embed layer {self.name!r} must use "
                              f"mode='grow_out' (vocab-anchored input)")
+        if (self.kind == "experts") != (self.spec.experts > 1):
+            raise ValueError(f"layer {self.name!r}: kind 'experts' goes "
+                             f"with an expert-bank spec and only with one")
 
     def apply(self, entry, x: Array, width: int) -> Array:
         if self.kind == "conv":
             return _apply_conv(entry, x, width, self.spec, stride=self.stride)
         if self.kind == "embed":
             return _apply_embed(entry, x, width, self.spec)
+        if self.kind == "experts":
+            raise ValueError(f"expert bank {self.name!r} is applied by the "
+                             f"model's grouped matmul over its materialized "
+                             f"weights")
         return _apply_dense(entry, x, width, self.spec)
 
     def materialized(self, entry, width: int) -> Array:
@@ -181,18 +192,33 @@ class FLModelDef:
     # the ComposedLayer dict the forward was assembled from (None for
     # defs built directly on raw specs)
     layers: Optional[Dict[str, ComposedLayer]] = None
+    # optional ``(weights, width, batch) -> (logits, stats)``: the same
+    # forward, also returning a dict of int arrays counted on the device
+    # (the expert model's routed pairs); the client's compiled steps
+    # return them beside their results
+    forward_stats: Optional[Callable] = None
 
     @classmethod
     def from_layers(cls, name: str, layers: Dict[str, ComposedLayer],
                     forward: Callable, flops_per_sample: Callable,
-                    num_classes: int, *, input_key: str = "x") -> "FLModelDef":
+                    num_classes: int, *, input_key: str = "x",
+                    forward_stats: Optional[Callable] = None
+                    ) -> "FLModelDef":
         """Assemble a def from an ordered ComposedLayer dict: the specs
         and hints tables are projections of the layers, so they can
         never drift apart."""
         specs = {n: layer.spec for n, layer in layers.items()}
         hints = {n: layer.hint for n, layer in layers.items()}
         return cls(name, specs, forward, flops_per_sample, num_classes,
-                   hints, input_key=input_key, layers=layers)
+                   hints, input_key=input_key, layers=layers,
+                   forward_stats=forward_stats)
+
+    def forward_with_stats(self, w, width: int, batch):
+        """``(logits, stats)``; ``stats`` is empty for a model that
+        counts nothing, so its compiled steps gain no output."""
+        if self.forward_stats is None:
+            return self.forward(w, width, batch), {}
+        return self.forward_stats(w, width, batch)
 
     # ---- factorized parameterisation -----------------------------------
     def init_factorized(self, key) -> Dict[str, Dict[str, Array]]:

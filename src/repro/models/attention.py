@@ -109,11 +109,13 @@ def flash_attention(
     kv_chunk: int = 1024,
     valid_len: Optional[Array] = None,
     skip_masked_blocks: bool = False,
+    scale: Optional[float] = None,
 ) -> Array:
     """Streaming-softmax attention.
 
     Args:
-      q: (B, Sq, KV, G, D);  k/v: (B, Sk, KV, D).
+      q: (B, Sq, KV, G, D);  k: (B, Sk, KV, D);  v: (B, Sk, KV, Dv) — V's
+        head dim may differ from Q/K's (latent attention: 192 vs 128).
       causal: apply causal mask with q positions aligned to the *end* of k
         (standard self-attention when Sq == Sk).
       window: sliding-window size (0 = full).
@@ -121,10 +123,12 @@ def flash_attention(
       skip_masked_blocks: unroll the outer loop and statically skip KV
         chunks that are entirely masked by causality/window (perf variant —
         identical output, fewer FLOPs; see EXPERIMENTS.md §Perf).
+      scale: the softmax scale; ``D ** -0.5`` when None.
 
-    Returns (B, Sq, KV, G, D).
+    Returns (B, Sq, KV, G, Dv).
     """
     B, Sq, KV, G, D = q.shape
+    Dv = v.shape[-1]
     Sk = k.shape[1]
     q_chunk = min(q_chunk, Sq)
     kv_chunk = min(kv_chunk, Sk)
@@ -134,12 +138,13 @@ def flash_attention(
     k = _pad_to(k, Sk + kpad, 1)
     v = _pad_to(v, Sk + kpad, 1)
     nq, nk = (Sq + qpad) // q_chunk, (Sk + kpad) // kv_chunk
-    scale = D ** -0.5
+    if scale is None:
+        scale = D ** -0.5
     q_offset = Sk - Sq  # causal alignment (q last token attends to k last)
 
     kq = jnp.moveaxis(q.reshape(B, nq, q_chunk, KV, G, D), 1, 0)
     kk = jnp.moveaxis(k.reshape(B, nk, kv_chunk, KV, D), 1, 0)
-    kv = jnp.moveaxis(v.reshape(B, nk, kv_chunk, KV, D), 1, 0)
+    kv = jnp.moveaxis(v.reshape(B, nk, kv_chunk, KV, Dv), 1, 0)
 
     def _one_q_chunk(qc, qi, kk, kv, nk_eff):
         qpos = qi * q_chunk + jnp.arange(q_chunk) + q_offset
@@ -175,7 +180,7 @@ def flash_attention(
 
         m0 = jnp.full((B, KV, G, q_chunk), NEG_INF, jnp.float32)
         l0 = jnp.zeros((B, KV, G, q_chunk), jnp.float32)
-        a0 = jnp.zeros((B, KV, G, q_chunk, D), jnp.float32)
+        a0 = jnp.zeros((B, KV, G, q_chunk, Dv), jnp.float32)
         if skip_masked_blocks:
             # static python loop; only blocks intersecting the causal/window
             # band are executed.
@@ -196,7 +201,7 @@ def flash_attention(
                 body, (m0, l0, a0), (kk, kv, jnp.arange(nk_eff))
             )
         out = acc / jnp.maximum(l[..., None], 1e-30)
-        return jnp.moveaxis(out, 3, 1)  # (B, q_chunk, KV, G, D)
+        return jnp.moveaxis(out, 3, 1)  # (B, q_chunk, KV, G, Dv)
 
     if skip_masked_blocks:
         outs = [ _one_q_chunk(kq[i], i, kk, kv, nk) for i in range(nq) ]
@@ -206,7 +211,7 @@ def flash_attention(
             lambda xs: _one_q_chunk(xs[0], xs[1], kk, kv, nk),
             (kq, jnp.arange(nq)),
         )
-    out = jnp.moveaxis(out, 0, 1).reshape(B, nq * q_chunk, KV, G, D)
+    out = jnp.moveaxis(out, 0, 1).reshape(B, nq * q_chunk, KV, G, Dv)
     return out[:, :Sq].astype(q.dtype)
 
 

@@ -72,6 +72,10 @@ KERNELS = {
     "compose_pallas": (
         lambda v, u: compose_pallas(v, u, interpret=False),
         [((1, D_BASE, R), f32), ((P * P, R, FF_BASE), f32)]),
+    # an expert bank's compose: one batched call over its 8 experts
+    "compose_pallas-expert_bank": (
+        lambda v, u: compose_pallas(v, u, interpret=False),
+        [((8, 1, D_BASE, R), f32), ((8, P * P, R, FF_BASE), f32)]),
     # rank-space dense apply: square projection and the grow_in head
     "rank_apply_pallas-square": (
         lambda x, v, u: rank_apply_pallas(x, v, u, interpret=False),
@@ -165,3 +169,20 @@ def test_transformer_cohort_step_compiles_for_v5e(one_chip, monkeypatch):
     txt = train_fn.lower(stacked, {"tokens": tok, "labels": tok}, taus,
                          0.05).compile().as_text()
     assert "tpu_custom_call" in txt
+
+
+def test_routed_experts_compile_for_v5e(one_chip):
+    """The grouped expert matmuls of ``moe_ffn`` (8 held experts of 64,
+    top-6), forward and backward, lower to the chip's ragged-dot kernel."""
+    from repro.fl.transformer import moe_ffn
+
+    def loss(x, router, gate, up, down):
+        y, _ = moe_ffn(x, router, gate, up, down, top_k=6)
+        return jnp.sum(y * y)
+
+    txt = _compiled_text(
+        jax.grad(loss, argnums=(0, 2, 3, 4)),
+        [((M, D_BASE), f32), ((D_BASE, 64), f32),
+         ((8, D_BASE, FF_BASE), f32), ((8, D_BASE, FF_BASE), f32),
+         ((8, FF_BASE, D_BASE), f32)], one_chip)
+    assert "ragged-dot" in txt and "tpu_custom_call" in txt
