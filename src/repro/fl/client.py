@@ -73,6 +73,40 @@ def _jitted_fns(model: FLModelDef, width: int, factorized: bool,
     return loss_jit, grad_fn, sgd_step
 
 
+@functools.lru_cache(maxsize=64)
+def _jitted_estimate(model: FLModelDef, width: int, factorized: bool,
+                     forward_impl: str = "auto", calibration=None):
+    """The client estimate step as one program, keyed like
+    ``_jitted_fns``: ``(params0, params, batches) -> (estimates, stats)``,
+    ``estimator.client_estimates`` traced around ``_jitted_fns``'s
+    ``grad_fn``.  ``estimates`` is the (L, sigma^2, G^2) dict; ``stats``
+    the four gradients' forward counts in call order (three at
+    ``params0``, then one at ``params``)."""
+    _, grad_fn, _ = _jitted_fns(model, width, factorized, forward_impl,
+                                calibration)
+
+    @jax.jit
+    def estimate(params0, params, batches):
+        stats, done = [], []
+
+        def grad(p, b):
+            # one gradient at a time: each waits for the one before, so
+            # no two hold their activations at once (left to overlap, the
+            # DeepSeek cell's width-4 program needs 15.5 GB of
+            # temporaries on a v5e, chained 6.5)
+            if done:
+                p, b, _ = jax.lax.optimization_barrier((p, b, done[-1]))
+            g, s = grad_fn(p, b)
+            stats.append(s)
+            done.append(g)
+            return g
+
+        est = estimator.client_estimates(grad, params0, params, batches)
+        return est, stats
+
+    return estimate
+
+
 def _sum_stats(forward: list, backward: list) -> Dict[str, int]:
     """A client-round's counts from the per-program ``stats`` of its
     forward-only (``forward``) and forward-and-backward (``backward``)
@@ -139,10 +173,12 @@ def local_train(
     ``obs`` records the ``trainer.sgd``, ``trainer.loss`` and
     ``trainer.estimate`` wall spans (nothing with the default no-op);
     with it on, the forward's counts of every program the client ran
-    come back in ``ClientResult.stats``, read after the estimates.
+    come back in ``ClientResult.stats``, read after the estimates.  The
+    estimates run as one compiled program (``_jitted_estimate``) and
+    come back in one read.
     """
-    loss_jit, grad_fn, sgd_step = _jitted_fns(model, width, factorized,
-                                              forward_impl, calibration)
+    loss_jit, _, sgd_step = _jitted_fns(model, width, factorized,
+                                        forward_impl, calibration)
     params0 = reduced_params
     params = params0
     n = len(y)
@@ -157,26 +193,25 @@ def local_train(
             params, stats = sgd_step(params, batch, lr)
             bwd_stats.append(stats)
 
-    def grad(p, b):
-        g, stats = grad_fn(p, b)
-        bwd_stats.append(stats)
-        return g
-
     est = {}
     with obs.wall_span("trainer.loss"):
         loss_b, stats_b = loss_jit(params0, first_batch)
         loss_a, stats_a = loss_jit(params, first_batch)
         fwd_stats += [stats_b, stats_a]
-        loss_b, loss_a = float(loss_b), float(loss_a)
+        loss_b, loss_a = (float(v) for v in jax.device_get((loss_b, loss_a)))
     if estimate:
-        with obs.wall_span("trainer.estimate"):
+        with obs.wall_span("trainer.estimate", compiled=1):
             batches = [
                 data_batch(model, x, y,
                            rng.integers(0, n, min(batch_size, n)))
                 for _ in range(3)
             ]
-            est = estimator.client_estimates(grad, params0, params, batches)
-            est = {k: float(v) for k, v in est.items()}
+            est, stats = _jitted_estimate(model, width, factorized,
+                                          forward_impl, calibration)(
+                params0, params, batches)
+            bwd_stats += stats
+            est = {k: float(v) for k, v in jax.device_get(est).items()}
+        obs.counter_add("trainer.estimate_compiled_clients")
     counted = (_sum_stats(fwd_stats, bwd_stats)
                if obs.enabled and model.forward_stats is not None else {})
     return ClientResult(params, est, loss_b, loss_a, counted)

@@ -30,7 +30,9 @@ SPANS: Dict[str, str] = {
     "trainer.loss":
         "the first batch's loss before and after the steps, read back",
     "trainer.estimate":
-        "the three estimate batches and (L, sigma^2, G^2), read back",
+        "the three estimate batches and (L, sigma^2, G^2) as one compiled "
+        "program, read back once (attribute compiled=1, counter "
+        "trainer.estimate_compiled_clients)",
     "trainer.pull":
         "trained params copied to the host (counter trainer.d2h_bytes)",
     "trainer.host_stage":

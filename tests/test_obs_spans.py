@@ -7,7 +7,9 @@ host plane with the nesting the recorder's ``parent`` fields give; the
 merge's ``merge.h2d_bytes`` equals the bytes of the client blocks, ids
 and bases it uploads, its ``merge.prep`` counts every client as
 scattered on the device (``device_scatter``), the trainer's
-``trainer.d2h_bytes`` equals the bytes of the params it returned; with
+``trainer.d2h_bytes`` equals the bytes of the params it returned and
+every client's estimate runs as the compiled program (``compiled`` on
+``trainer.estimate``, counter ``trainer.estimate_compiled_clients``); with
 telemetry off no span is opened at all.
 """
 
@@ -171,6 +173,19 @@ def test_merge_prep_counts_clients_scattered_on_the_device(image_setup):
     for e in preps:
         assert e["attrs"]["device_scatter"] == e["attrs"]["clients"]
     assert eng.obs.counters["merge.device_scatter_clients"] == 5
+    eng.close()
+
+
+def test_every_client_estimate_runs_compiled(image_setup):
+    model, px, py, test = image_setup
+    eng = build_runner("heroes", model, px, py, test,
+                       cfg=_cfg(telemetry="memory", clients_per_round=3))
+    for clients in ([0, 4, 7], [1, 2]):
+        state, assigns = eng.assignment.assign(eng.state, clients)
+        eng.trainer.train_all(state, assigns)
+    spans = eng.obs.sinks[0].spans("trainer.estimate")
+    assert [e["attrs"]["compiled"] for e in spans] == [1] * 5
+    assert eng.obs.counters["trainer.estimate_compiled_clients"] == 5
     eng.close()
 
 
