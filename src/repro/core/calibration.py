@@ -56,7 +56,7 @@ class RankPathCalibration:
     """The two measured knobs the auto cost model consumes.
 
     Frozen + hashable on purpose: instances ride in the client/trainer
-    jit-cache keys (``client._jitted_fns``, ``trainers._cohort_fns``),
+    jit-cache keys (``client.client_step``, ``trainers._cohort_fns``),
     so a config override can never reuse a cache entry compiled under a
     different calibration.
     """

@@ -124,19 +124,19 @@ def make_shards(x: np.ndarray, y: np.ndarray, parts,
     return [x[p] for p in parts], [y[p] for p in parts]
 
 
-def round_batch_indices(seed: int, rnd: int, n: int, num_samples: int,
-                        tau: int, batch_size: int, estimate: bool,
-                        tau_pad: Optional[int] = None
-                        ) -> Tuple[np.ndarray, Optional[np.ndarray]]:
-    """The engine's host RNG contract, in one place.
+def draw_batch_indices(rng: np.random.Generator, num_samples: int,
+                       tau: int, batch_size: int, estimate: bool,
+                       tau_pad: Optional[int] = None
+                       ) -> Tuple[np.ndarray, Optional[np.ndarray]]:
+    """One client-round's minibatch indices from ``rng``: tau training
+    draws, then (with ``estimate``) 3 estimate draws, each of
+    ``batch_size`` indices into the client's ``num_samples``.
 
     Returns ``(idx, est_idx)`` with ``idx`` of shape
     ``(tau_pad or tau, batch_size)`` (padding steps repeat the last real
     batch — they are masked no-ops in the cohort step) and ``est_idx``
-    of shape ``(3, batch_size)`` or None.  Draw order matches
-    ``local_train``: tau training draws, then 3 estimate draws.
+    of shape ``(3, batch_size)`` or None.
     """
-    rng = np.random.default_rng((seed, rnd, n))
     idx = np.stack([rng.integers(0, num_samples, batch_size)
                     for _ in range(tau)])
     pad = (tau_pad or tau) - tau
@@ -147,6 +147,16 @@ def round_batch_indices(seed: int, rnd: int, n: int, num_samples: int,
         est_idx = np.stack([rng.integers(0, num_samples, batch_size)
                             for _ in range(3)])
     return idx, est_idx
+
+
+def round_batch_indices(seed: int, rnd: int, n: int, num_samples: int,
+                        tau: int, batch_size: int, estimate: bool,
+                        tau_pad: Optional[int] = None
+                        ) -> Tuple[np.ndarray, Optional[np.ndarray]]:
+    """The engine's host RNG contract: :func:`draw_batch_indices` from
+    client ``n``'s round-``rnd`` stream ``default_rng((seed, rnd, n))``."""
+    return draw_batch_indices(np.random.default_rng((seed, rnd, n)),
+                              num_samples, tau, batch_size, estimate, tau_pad)
 
 
 def stack_client_shards(per_client: Sequence[np.ndarray], chunks: int,

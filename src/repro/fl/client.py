@@ -15,13 +15,14 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Any, Dict
+from typing import Any, Callable, Dict, NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.core import estimator
+from repro.data.streaming import draw_batch_indices
 from repro.fl.models import FLModelDef
 from repro.obs.recorder import NOOP
 
@@ -40,19 +41,37 @@ def _ce(logits: Array, labels: Array) -> Array:
     return jnp.mean(logz - gold)
 
 
-@functools.lru_cache(maxsize=64)
-def _jitted_fns(model: FLModelDef, width: int, factorized: bool,
-                forward_impl: str = "auto", calibration=None):
-    # Keyed on the model *instance* (FLModelDef hashes by identity): the
-    # old string registry key dropped constructor kwargs that are not part
-    # of the encoding (e.g. ``in_ch``), silently training the wrong model.
-    # ``calibration`` (a frozen RankPathCalibration, or None = the
-    # per-process measurement) joins the key so two configs with
-    # different cost-model overrides never share impl choices.
+class ClientStep(NamedTuple):
+    """One client step's programs, each a lazy ``jax.jit`` wrapper (it
+    compiles on its first call), all over the one ``loss_fn``.  Every
+    program also returns the forward's device-side counts
+    (``FLModelDef.forward_stats``; an empty dict, so no output at all,
+    for a model that counts nothing)."""
 
-    # Every program also returns the forward's device-side counts
-    # (``FLModelDef.forward_stats``; an empty dict, so no output at all,
-    # for a model that counts nothing).
+    loss: Callable      # (params, batch) -> (loss, stats)
+    grad: Callable      # (params, batch) -> (grads, stats)
+    sgd: Callable       # (params, batch, lr) -> (params', stats)
+    # (params, anchor, batch, lr, mu) -> (params', stats): the FedProx
+    # step on f(w) + (mu/2) ||w - anchor||^2
+    prox: Callable
+    # (params0, params, batches) -> (estimates, stats): the
+    # (L, sigma^2, G^2) dict, and the four gradients' counts in call
+    # order (three at params0, then one at params)
+    estimate: Callable
+
+
+@functools.lru_cache(maxsize=64)
+def client_step(model: FLModelDef, width: int, factorized: bool,
+                forward_impl: str = "auto", calibration=None) -> ClientStep:
+    """The client step's programs, cached per key.
+
+    Keyed on the model *instance* (FLModelDef hashes by identity): a
+    string registry key would drop constructor kwargs that are not part
+    of the encoding (e.g. ``in_ch``), silently training the wrong model.
+    ``calibration`` (a frozen RankPathCalibration, or None = the
+    per-process measurement) joins the key so two configs with
+    different cost-model overrides never share impl choices.
+    """
 
     def loss_fn(params, batch):
         w = (model.prepare_weights(params, width, batch, forward_impl,
@@ -61,29 +80,21 @@ def _jitted_fns(model: FLModelDef, width: int, factorized: bool,
         logits, stats = model.forward_with_stats(w, width, batch)
         return _ce(logits, batch["labels"]), stats
 
-    grad_fn = jax.jit(jax.grad(loss_fn, has_aux=True))
-    loss_jit = jax.jit(loss_fn)
+    grad_fn = jax.grad(loss_fn, has_aux=True)
+    grad_jit = jax.jit(grad_fn)
 
     @jax.jit
     def sgd_step(params, batch, lr):
-        g, stats = jax.grad(loss_fn, has_aux=True)(params, batch)
+        g, stats = grad_fn(params, batch)
         return (jax.tree_util.tree_map(lambda p, gg: p - lr * gg, params, g),
                 stats)
 
-    return loss_jit, grad_fn, sgd_step
-
-
-@functools.lru_cache(maxsize=64)
-def _jitted_estimate(model: FLModelDef, width: int, factorized: bool,
-                     forward_impl: str = "auto", calibration=None):
-    """The client estimate step as one program, keyed like
-    ``_jitted_fns``: ``(params0, params, batches) -> (estimates, stats)``,
-    ``estimator.client_estimates`` traced around ``_jitted_fns``'s
-    ``grad_fn``.  ``estimates`` is the (L, sigma^2, G^2) dict; ``stats``
-    the four gradients' forward counts in call order (three at
-    ``params0``, then one at ``params``)."""
-    _, grad_fn, _ = _jitted_fns(model, width, factorized, forward_impl,
-                                calibration)
+    @jax.jit
+    def prox_step(params, anchor, batch, lr, mu):
+        g, stats = grad_fn(params, batch)
+        return (jax.tree_util.tree_map(
+            lambda p, a, gg: p - lr * (gg + mu * (p - a)), params, anchor, g),
+            stats)
 
     @jax.jit
     def estimate(params0, params, batches):
@@ -96,7 +107,7 @@ def _jitted_estimate(model: FLModelDef, width: int, factorized: bool,
             # temporaries on a v5e, chained 6.5)
             if done:
                 p, b, _ = jax.lax.optimization_barrier((p, b, done[-1]))
-            g, s = grad_fn(p, b)
+            g, s = grad_jit(p, b)
             stats.append(s)
             done.append(g)
             return g
@@ -104,7 +115,8 @@ def _jitted_estimate(model: FLModelDef, width: int, factorized: bool,
         est = estimator.client_estimates(grad, params0, params, batches)
         return est, stats
 
-    return estimate
+    return ClientStep(jax.jit(loss_fn), grad_jit, sgd_step, prox_step,
+                      estimate)
 
 
 def _sum_stats(forward: list, backward: list) -> Dict[str, int]:
@@ -161,6 +173,7 @@ def local_train(
     forward_impl: str = "auto",
     calibration=None,
     obs=NOOP,
+    prox_mu: Optional[float] = None,
 ) -> ClientResult:
     """tau local SGD iterations (Alg. 2 lines 4-9).
 
@@ -170,45 +183,46 @@ def local_train(
     applies factors in rank space wherever the measured cost model says
     it is cheaper (``calibration`` carries an FLConfig override; None =
     the per-process measurement).  Ignored when ``factorized=False``.
+    The minibatch indices come from ``rng`` in the order of
+    :func:`~repro.data.streaming.draw_batch_indices`.  With ``prox_mu``
+    every step is the FedProx step anchored at ``reduced_params``.
     ``obs`` records the ``trainer.sgd``, ``trainer.loss`` and
     ``trainer.estimate`` wall spans (nothing with the default no-op);
     with it on, the forward's counts of every program the client ran
     come back in ``ClientResult.stats``, read after the estimates.  The
-    estimates run as one compiled program (``_jitted_estimate``) and
+    estimates run as one compiled program (``ClientStep.estimate``) and
     come back in one read.
     """
-    loss_jit, _, sgd_step = _jitted_fns(model, width, factorized,
-                                        forward_impl, calibration)
+    step = client_step(model, width, factorized, forward_impl, calibration)
     params0 = reduced_params
     params = params0
     n = len(y)
+    idx, est_idx = draw_batch_indices(rng, n, max(tau, 1),
+                                      min(batch_size, n), estimate)
     first_batch = None
     fwd_stats, bwd_stats = [], []
     with obs.wall_span("trainer.sgd"):
-        for _ in range(max(tau, 1)):
-            idx = rng.integers(0, n, min(batch_size, n))
-            batch = data_batch(model, x, y, idx)
+        for i in idx:
+            batch = data_batch(model, x, y, i)
             if first_batch is None:
                 first_batch = batch
-            params, stats = sgd_step(params, batch, lr)
+            if prox_mu is None:
+                params, stats = step.sgd(params, batch, lr)
+            else:
+                params, stats = step.prox(params, params0, batch, lr,
+                                          prox_mu)
             bwd_stats.append(stats)
 
     est = {}
     with obs.wall_span("trainer.loss"):
-        loss_b, stats_b = loss_jit(params0, first_batch)
-        loss_a, stats_a = loss_jit(params, first_batch)
+        loss_b, stats_b = step.loss(params0, first_batch)
+        loss_a, stats_a = step.loss(params, first_batch)
         fwd_stats += [stats_b, stats_a]
         loss_b, loss_a = (float(v) for v in jax.device_get((loss_b, loss_a)))
     if estimate:
         with obs.wall_span("trainer.estimate", compiled=1):
-            batches = [
-                data_batch(model, x, y,
-                           rng.integers(0, n, min(batch_size, n)))
-                for _ in range(3)
-            ]
-            est, stats = _jitted_estimate(model, width, factorized,
-                                          forward_impl, calibration)(
-                params0, params, batches)
+            batches = [data_batch(model, x, y, i) for i in est_idx]
+            est, stats = step.estimate(params0, params, batches)
             bwd_stats += stats
             est = {k: float(v) for k, v in jax.device_get(est).items()}
         obs.counter_add("trainer.estimate_compiled_clients")

@@ -129,10 +129,10 @@ class LocalTrainer(Component):
     Reads the global view through ``aggregator.client_params(state, ...)``
     and the round index from ``state.round`` (the per-client data/rng
     streams are keyed ``(seed, round, client)``).  Returned
-    ``ClientResult.params`` trees are host-resident (numpy): the
-    collective aggregation backend scatters them into dense zero-padded
-    contributions + masks on the host and ships the stacked cohort to
-    the device in one transfer per round.
+    ``ClientResult.params`` trees are host-resident (numpy): the trained
+    params are pulled to the host, and the collective aggregation
+    backend then uploads each client's blocks and scatters them into the
+    dense zero-padded contributions + masks on the device.
     """
 
     def train_all(self, state: ServerState,

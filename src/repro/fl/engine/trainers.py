@@ -22,17 +22,21 @@ so the two backends see the same data order.  Shards may be lazy
 gathered — and the cohort backend prefetches the next group's host
 batches on a background thread while the device runs the current one.
 
-``ProximalTrainer`` is the FedProx local solver: the same sequential
-contract with the proximal pull ``mu * (w - w_global)`` added to every
-SGD step, so FedProx drops in as a scheme bundle without core changes.
+``ProximalTrainer`` is the FedProx local solver: the sequential trainer
+with the proximal pull ``mu * (w - w_global)`` added to every SGD step,
+so FedProx drops in as a scheme bundle without core changes.
+
+Every backend runs the one client step of :func:`repro.fl.client.
+client_step` (loss, gradient, SGD/proximal update, estimates), so a
+change to the step is made once.
 
 Result-params contract: backends return *host-resident* (numpy) param
 trees — the collective aggregation backend (repro.fl.engine.collective)
-scatters them into dense zero-padded contributions in one numpy pass and
-ships the stacked cohort to the device once, instead of K round-trips.
-The one exception is the mesh-sharded cohort path feeding the collective
-backend: there the trained stack stays *device-resident* on the cohort
-axis (``ClientResult.params`` is a lazy
+uploads each client's blocks and scatters them into the dense
+zero-padded contributions on the device.  The one exception is the
+mesh-sharded cohort path feeding the collective backend: there the
+trained stack stays *device-resident* on the cohort axis
+(``ClientResult.params`` is a lazy
 :class:`~repro.fl.engine.collective.CohortSlice``) and the merge
 consumes it without a gather/rescatter; ``ClientResult.host_params()``
 recovers the numpy tree everywhere else.
@@ -49,9 +53,8 @@ import numpy as np
 from jax import shard_map
 from jax.sharding import NamedSharding
 
-from repro.core import estimator
 from repro.core.calibration import for_dispatch
-from repro.data.streaming import round_batch_indices, stack_client_shards
+from repro.data.streaming import stack_client_shards
 from repro.fl import client as client_lib
 from repro.fl.client import ClientResult
 from repro.fl.engine.base import Assignment, LocalTrainer
@@ -96,39 +99,46 @@ class SequentialTrainer(LocalTrainer):
     """One ``local_train`` call per client (legacy-equivalent backend).
 
     Result params are pulled to the host (numpy) — the contract shared
-    with :class:`CohortTrainer` — so the collective aggregation prep can
-    build its dense zero-padded contributions in one numpy pass instead
-    of K per-client device round-trips.
+    with :class:`CohortTrainer` — and the collective merge uploads each
+    client's blocks and scatters them on the device.
     """
+
+    name = "sequential"  # the ``trainer`` label of trainer.jit_recompiles
+
+    def prox_mu(self) -> Optional[float]:
+        """The proximal coefficient of every step; None = plain SGD."""
+        return None
 
     def train_all(self, state, assigns: Dict[int, Assignment],
                   ) -> Dict[int, ClientResult]:
         eng = self.eng
         obs = eng.obs
         cal = for_dispatch(eng.cfg)
+        mu = self.prox_mu()
         out = {}
         for n, a in assigns.items():
             with obs.wall_span("trainer.client_params", client=int(n)):
                 params = eng.aggregator.client_params(state, n, a)
             before = None
             if obs.enabled:
-                # the per-step jits live in client._jitted_fns (lru
-                # cached — this lookup is the one local_train makes)
-                _, _, sgd_step = client_lib._jitted_fns(
+                # the lru-cached lookup local_train makes
+                step = client_lib.client_step(
                     eng.model, a["width"], eng.factorized,
                     eng.cfg.forward_impl, cal)
-                before = _cache_size(sgd_step)
+                program = step.sgd if mu is None else step.prox
+                before = _cache_size(program)
             with obs.wall_span("trainer.local_train", client=int(n),
                                width=int(a["width"]),
                                tau=int(a["tau"])) as span:
+                x, y = eng.data.shard(n)
                 res = client_lib.local_train(
-                    eng.model, params, a["width"], a["tau"],
-                    eng.parts_x[n], eng.parts_y[n], eng.cfg.lr,
+                    eng.model, params, a["width"], a["tau"], x, y,
+                    eng.cfg.lr,
                     np.random.default_rng((eng.cfg.seed, state.round, n)),
                     eng.cfg.batch_size, factorized=eng.factorized,
                     estimate=eng.estimate,
                     forward_impl=eng.cfg.forward_impl,
-                    calibration=cal, obs=obs,
+                    calibration=cal, obs=obs, prox_mu=mu,
                 )
                 if res.stats:
                     # the forward's counts (the expert model's moe.*) ride
@@ -138,12 +148,29 @@ class SequentialTrainer(LocalTrainer):
                         if not k.startswith("backward."):
                             obs.counter_add(k, v)
             if obs.enabled:
-                _count_recompiles(obs, sgd_step, before,
-                                  trainer="sequential",
+                _count_recompiles(obs, program, before, trainer=self.name,
                                   width=int(a["width"]))
             out[n] = ClientResult(_pull(obs, res.params), res.estimates,
                                   res.loss_before, res.loss_after)
         return out
+
+
+class ProximalTrainer(SequentialTrainer):
+    """FedProx local solver: SGD on ``f(w) + (mu/2) ||w - w_global||^2``.
+
+    The sequential trainer with every step anchored at the received
+    global view (``ClientStep.prox``): the same spans, RNG contract and
+    compiled estimates, and ``mu = 0`` reproduces FedAvg's local updates
+    bitwise.  ``mu`` defaults to ``FLConfig.prox_mu``.
+    """
+
+    name = "proximal"
+
+    def __init__(self, mu: Optional[float] = None):
+        self._mu = mu
+
+    def prox_mu(self) -> float:
+        return self.eng.cfg.prox_mu if self._mu is None else self._mu
 
 
 @functools.lru_cache(maxsize=32)
@@ -164,20 +191,13 @@ def _cohort_fns(model: FLModelDef, width: int, factorized: bool, mesh=None,
     per-client loss applies factors in rank space — under the client
     vmap the rank contractions batch over the cohort axis exactly like
     the dense ops, so the whole stacked cohort shares the cheaper
-    path in the ONE compiled call."""
+    path in the ONE compiled call.
 
-    def loss_fn(params, batch):
-        w = (model.prepare_weights(params, width, batch, forward_impl,
-                                   calibration)
-             if factorized else {k: v for k, v in params.items()})
-        logits = model.forward(w, width, batch)
-        return client_lib._ce(logits, batch["labels"])
-
-    grad_fn = jax.grad(loss_fn)
-
-    def sgd_step(params, batch, lr):
-        g = grad_fn(params, batch)
-        return jax.tree_util.tree_map(lambda p, gg: p - lr * gg, params, g)
+    The step is the sequential trainer's (:func:`repro.fl.client.
+    client_step`): the same loss, SGD update and estimate program,
+    vmapped over the client axis; the forward's counts are dropped."""
+    step = client_lib.client_step(model, width, factorized, forward_impl,
+                                  calibration)
 
     def train(stacked, batches, taus, lr):
         """Unrolled tau steps, vmap over the client axis — one compiled call.
@@ -195,7 +215,7 @@ def _cohort_fns(model: FLModelDef, width: int, factorized: bool, mesh=None,
 
         def body(params, xs):
             t, batch = xs
-            new = jax.vmap(lambda p, b: sgd_step(p, b, lr))(params, batch)
+            new, _ = jax.vmap(lambda p, b: step.sgd(p, b, lr))(params, batch)
             keep = t < taus
             params = jax.tree_util.tree_map(
                 lambda nw, old: jnp.where(
@@ -215,8 +235,8 @@ def _cohort_fns(model: FLModelDef, width: int, factorized: bool, mesh=None,
             lambda v: jnp.where(
                 live.reshape(live.shape + (1,) * (v.ndim - 1)), v, 0), final)
         first = jax.tree_util.tree_map(lambda v: v[0], batches)
-        loss_b = jax.vmap(loss_fn)(stacked, first)
-        loss_a = jax.vmap(loss_fn)(final, first)
+        loss_b, _ = jax.vmap(step.loss)(stacked, first)
+        loss_a, _ = jax.vmap(step.loss)(final, first)
         return final, loss_b, loss_a
 
     def estimates(params0, params_t, est_batches):
@@ -225,7 +245,7 @@ def _cohort_fns(model: FLModelDef, width: int, factorized: bool, mesh=None,
         def per_client(p0, pt, eb):
             bs = [jax.tree_util.tree_map(lambda x, i=i: x[i], eb)
                   for i in range(3)]
-            return estimator.client_estimates(grad_fn, p0, pt, bs)
+            return step.estimate(p0, pt, bs)[0]
 
         return jax.vmap(per_client)(params0, params_t, est_batches)
 
@@ -441,100 +461,4 @@ class CohortTrainer(LocalTrainer):
             params = jax.tree_util.tree_map(lambda v, j=j: v[j], final)
             est = {k: float(v[j]) for k, v in ests.items()} if ests else {}
             out[n] = ClientResult(params, est, float(loss_b[j]), float(loss_a[j]))
-        return out
-
-
-@functools.lru_cache(maxsize=32)
-def _prox_fns(model: FLModelDef, width: int, factorized: bool,
-              forward_impl: str = "auto", calibration=None):
-    """Compiled FedProx step/loss/grad, keyed on the model instance."""
-
-    def loss_fn(params, batch):
-        w = (model.prepare_weights(params, width, batch, forward_impl,
-                                   calibration)
-             if factorized else {k: v for k, v in params.items()})
-        logits = model.forward(w, width, batch)
-        return client_lib._ce(logits, batch["labels"])
-
-    grad_fn = jax.grad(loss_fn)
-
-    @jax.jit
-    def prox_step(params, anchor, batch, lr, mu):
-        g = grad_fn(params, batch)
-        return jax.tree_util.tree_map(
-            lambda p, a, gg: p - lr * (gg + mu * (p - a)), params, anchor, g)
-
-    return jax.jit(loss_fn), jax.jit(grad_fn), prox_step
-
-
-class ProximalTrainer(LocalTrainer):
-    """FedProx local solver: SGD on ``f(w) + (mu/2) ||w - w_global||^2``.
-
-    Identical dispatch/RNG contract to :class:`SequentialTrainer`
-    (minibatch indices come from the same ``round_batch_indices``
-    stream: tau training draws, then — when the scheme ships estimates —
-    3 estimate draws), with the proximal pull toward the received global
-    view added to every step — ``mu = 0`` reproduces FedAvg's local
-    updates bitwise.  ``mu`` defaults to ``FLConfig.prox_mu``.
-
-    When ``eng.estimate`` is set (Heroes/ADP adaptive policies using
-    FedProx as the local solver) the (L, sigma^2, G^2) estimates are
-    computed over the 3 estimate batches exactly as the sequential
-    backend does, so adaptive tau keeps its signals.
-    """
-
-    def __init__(self, mu: Optional[float] = None):
-        self._mu = mu
-
-    def train_all(self, state, assigns: Dict[int, Assignment],
-                  ) -> Dict[int, ClientResult]:
-        eng, cfg = self.eng, self.eng.cfg
-        obs = eng.obs
-        mu = cfg.prox_mu if self._mu is None else self._mu
-        xkey = eng.model.input_key
-        out: Dict[int, ClientResult] = {}
-        cal = for_dispatch(cfg)
-        for n, a in assigns.items():
-            loss_fn, grad_fn, prox_step = _prox_fns(
-                eng.model, a["width"], eng.factorized,
-                cfg.forward_impl, cal)
-            before = _cache_size(prox_step) if obs.enabled else None
-            with obs.wall_span("trainer.client_params", client=int(n)):
-                anchor = eng.aggregator.client_params(state, n, a)
-            # the span tree of SequentialTrainer / client.local_train
-            with obs.wall_span("trainer.local_train", client=int(n),
-                               width=int(a["width"]), tau=int(a["tau"])):
-                nsamp = eng.data.num_samples(n)
-                b_eff = min(cfg.batch_size, nsamp)
-                tau = max(a["tau"], 1)
-                idx, est_idx = round_batch_indices(cfg.seed, state.round, n,
-                                                   nsamp, tau, b_eff,
-                                                   estimate=eng.estimate)
-                with obs.wall_span("trainer.sgd"):
-                    params, first = anchor, None
-                    for t in range(tau):
-                        xb, yb = eng.data.gather(n, idx[t])
-                        batch = {xkey: jnp.asarray(xb),
-                                 "labels": jnp.asarray(yb)}
-                        if first is None:
-                            first = batch
-                        params = prox_step(params, anchor, batch, cfg.lr, mu)
-                with obs.wall_span("trainer.loss"):
-                    loss_b = float(loss_fn(anchor, first))
-                    loss_a = float(loss_fn(params, first))
-                est: Dict[str, float] = {}
-                if est_idx is not None:
-                    with obs.wall_span("trainer.estimate"):
-                        ebs = []
-                        for i in range(3):
-                            xb, yb = eng.data.gather(n, est_idx[i])
-                            ebs.append({xkey: jnp.asarray(xb),
-                                        "labels": jnp.asarray(yb)})
-                        est = estimator.client_estimates(grad_fn, anchor,
-                                                         params, ebs)
-                        est = {k: float(v) for k, v in est.items()}
-            if obs.enabled:
-                _count_recompiles(obs, prox_step, before, trainer="proximal",
-                                  width=int(a["width"]))
-            out[n] = ClientResult(_pull(obs, params), est, loss_b, loss_a)
         return out

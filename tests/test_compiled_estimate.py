@@ -1,9 +1,9 @@
 """The client estimate step as one compiled program
-(``repro.fl.client._jitted_estimate``) against the eager estimator it
-replaced.
+(``repro.fl.client.client_step(...).estimate``) against the eager
+estimator it replaced.
 
 The eager path — ``estimator.client_estimates`` run op by op around
-``_jitted_fns``'s ``grad_fn`` — lives only here, as the oracle: the
+the step's ``grad`` program — lives only here, as the oracle: the
 compiled estimates match it to float re-association, a Heroes history
 under ``materialize`` is the same field for field, the expert model's
 forward counts are the same, and a width compiles its estimate once.
@@ -30,12 +30,10 @@ TINY_MLA_MOE = dict(json.loads((CHIP / "tests" / "tiny-mla-moe.json")
                                .read_text())["model"], max_width=3)
 
 
-def _eager_estimate(model, width, factorized, forward_impl="auto",
-                    calibration=None):
+def _eager_estimate(grad_fn):
     """The estimate step as the sequential trainer ran it before it was
-    compiled: four jitted gradients, the estimator op by op."""
-    _, grad_fn, _ = client_lib._jitted_fns(model, width, factorized,
-                                           forward_impl, calibration)
+    compiled: four jitted gradients (``grad_fn``, the step's ``grad``
+    program), the estimator op by op."""
 
     def estimate(params0, params, batches):
         stats = []
@@ -49,6 +47,17 @@ def _eager_estimate(model, width, factorized, forward_impl="auto",
                                           batches), stats
 
     return estimate
+
+
+def _use_eager_estimates(monkeypatch):
+    """Every client step from here on estimates op by op."""
+    compiled = client_lib.client_step
+
+    def step(*key):
+        s = compiled(*key)
+        return s._replace(estimate=_eager_estimate(s.grad))
+
+    monkeypatch.setattr(client_lib, "client_step", step)
 
 
 def _reduced(model, width):
@@ -90,14 +99,10 @@ def test_compiled_estimates_match_the_eager_estimator(case):
     rng = np.random.default_rng(0)
     batches = [data_batch(model, x, y, rng.integers(0, len(y), 8))
                for _ in range(3)]
-    _, _, sgd_step = client_lib._jitted_fns(model, width, True,
-                                            "materialize")
-    params, _ = sgd_step(params0, batches[0], 0.05)
-    want, _ = _eager_estimate(model, width, True, "materialize")(
-        params0, params, batches)
-    got, stats = client_lib._jitted_estimate(model, width, True,
-                                             "materialize")(
-        params0, params, batches)
+    step = client_lib.client_step(model, width, True, "materialize")
+    params, _ = step.sgd(params0, batches[0], 0.05)
+    want, _ = _eager_estimate(step.grad)(params0, params, batches)
+    got, stats = step.estimate(params0, params, batches)
     assert len(stats) == 4
     assert set(got) == {"L", "sigma_sq", "grad_sq"}
     for k in want:
@@ -115,7 +120,7 @@ def test_history_equals_the_eager_estimators(scheme, monkeypatch):
                    tau_fixed=4, tau_max=15, estimate=True,
                    forward_impl="materialize")
     got = run_scheme(scheme, model, px, py, test, rounds=4, cfg=cfg)
-    monkeypatch.setattr(client_lib, "_jitted_estimate", _eager_estimate)
+    _use_eager_estimates(monkeypatch)
     want = run_scheme(scheme, model, px, py, test, rounds=4, cfg=cfg)
     assert [dataclasses.asdict(h) for h in got] == [
         dataclasses.asdict(h) for h in want]
@@ -136,7 +141,7 @@ def _train(case, seed, obs):
 
 def test_expert_counts_equal_the_eager_paths(mla_moe, monkeypatch):
     got = _train(mla_moe, 3, Recorder([MemorySink()]))
-    monkeypatch.setattr(client_lib, "_jitted_estimate", _eager_estimate)
+    _use_eager_estimates(monkeypatch)
     want = _train(mla_moe, 3, Recorder([MemorySink()]))
     for key in ("moe.routed_pairs", "backward.moe.routed_pairs",
                 "moe.pairs_total", "moe.expert_load_max",
@@ -154,8 +159,8 @@ def test_a_width_compiles_its_estimate_once(mla_moe):
     model, _, _ = mla_moe
     obs = Recorder([MemorySink()])
     _train(mla_moe, 4, obs)
-    program = client_lib._jitted_estimate(model, 2, True, "materialize",
-                                          None)
+    program = client_lib.client_step(model, 2, True, "materialize",
+                                     None).estimate
     before = _cache_size(program)
     assert before is not None and before >= 1
     _train(mla_moe, 5, obs)

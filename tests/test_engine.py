@@ -408,7 +408,7 @@ def test_eval_batches_cover_test_set(image_setup):
 
 
 # ---------------------------------------------------------------------------
-# jit-cache identity fix (repro.fl.client._jitted_fns)
+# jit-cache identity fix (repro.fl.client.client_step)
 # ---------------------------------------------------------------------------
 
 

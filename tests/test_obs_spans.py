@@ -222,8 +222,8 @@ def test_sequential_and_proximal_emit_the_same_span_tree(image_setup):
         state, assigns = eng.assignment.assign(eng.state, [2, 5])
         n0 = len(eng.obs.sinks[0].events)
         trainer.train_all(state, assigns)
-        trees.append([(e["name"], e["parent"])
+        trees.append([(e["name"], e["parent"], e["attrs"].get("compiled"))
                       for e in _wall(eng.obs.sinks[0].events[n0:])])
         eng.close()
     assert trees[0] == trees[1]
-    assert ("trainer.estimate", "trainer.local_train") in trees[0]
+    assert ("trainer.estimate", "trainer.local_train", 1) in trees[0]

@@ -28,7 +28,7 @@ from repro.core.composition import (CompositionPlan, CompositionSpec,
                                     gather_blocks, init_factors,
                                     rank_space_wins)
 from repro.fl import FLConfig, build_image_setup, run_scheme
-from repro.fl.client import _jitted_fns
+from repro.fl.client import client_step
 from repro.fl.models import make_cnn, make_resnet, make_rnn
 
 FIXTURES = Path(__file__).parent / "fixtures"
@@ -126,8 +126,10 @@ def test_gradient_parity_rank_space_vs_materialize(make, width, impl):
     model = make()
     red = _reduced(model, width)
     batch = _batch(model, jax.random.PRNGKey(3))
-    _, grad_mat, step_mat = _jitted_fns(model, width, True, "materialize")
-    _, grad_rank, step_rank = _jitted_fns(model, width, True, impl)
+    mat = client_step(model, width, True, "materialize")
+    rank = client_step(model, width, True, impl)
+    grad_mat, step_mat = mat.grad, mat.sgd
+    grad_rank, step_rank = rank.grad, rank.sgd
     # the programs return the forward's counts beside their
     # results (none for these models)
     g_mat, _ = grad_mat(red, batch)
@@ -256,8 +258,8 @@ def test_fused_compose_impl_gradient_parity():
     assert impls["fc"] == "fused_compose"
     red = _reduced(model, 3)
     batch = _batch(model, jax.random.PRNGKey(5), n=16)
-    _, grad_mat, _ = _jitted_fns(model, 3, True, "materialize")
-    _, grad_fus, _ = _jitted_fns(model, 3, True, "auto", cal)
+    grad_mat = client_step(model, 3, True, "materialize").grad
+    grad_fus = client_step(model, 3, True, "auto", cal).grad
     for a, b in zip(jax.tree_util.tree_leaves(grad_mat(red, batch)[0]),
                     jax.tree_util.tree_leaves(grad_fus(red, batch)[0])):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b),

@@ -24,7 +24,7 @@ import numpy as np
 import pytest
 
 from repro.fl import FLConfig, build_runner, build_setup, run_scheme
-from repro.fl.client import _jitted_fns, data_batch
+from repro.fl.client import client_step, data_batch
 from repro.fl.models import (MODEL_REGISTRY, ComposedLayer, CompositionSpec,
                              LayerHint, _apply_conv, _apply_dense,
                              _apply_embed, _materialized, get_model, make_cnn,
@@ -192,8 +192,10 @@ def test_transformer_gradient_parity(width, impl):
     model = make_transformer()
     red = _reduced(model, width)
     batch = _text_batch(jax.random.PRNGKey(3))
-    _, grad_mat, step_mat = _jitted_fns(model, width, True, "materialize")
-    _, grad_rank, step_rank = _jitted_fns(model, width, True, impl)
+    mat = client_step(model, width, True, "materialize")
+    rank = client_step(model, width, True, impl)
+    grad_mat, step_mat = mat.grad, mat.sgd
+    grad_rank, step_rank = rank.grad, rank.sgd
     # the programs return the forward's counts beside their
     # results (none for these models)
     g_mat, _ = grad_mat(red, batch)
