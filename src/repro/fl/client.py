@@ -148,15 +148,35 @@ class ClientResult:
     # ``_sum_stats``); read back only when telemetry is on
     stats: Dict[str, int] = dataclasses.field(default_factory=dict)
 
-    def host_params(self) -> Any:
-        """Params as a host pytree.
+    def host_params(self, obs=NOOP) -> Any:
+        """Params as a host (numpy) pytree, for consumers that need one.
 
-        Usually ``params`` itself (the numpy contract); a mesh-sharded
-        cohort trainer hands the collective backend lazy device-resident
-        slices instead, and this materializes them.
+        The trainers hand ``params`` over device-resident: the trained
+        pytree the client step produced (sequential and proximal
+        trainers), or a lazy view into a mesh-sharded cohort stack, which
+        this materializes.  A device pytree is copied to the host under
+        ``trainer.pull`` (see :func:`pull_to_host`); a numpy tree, as a
+        checkpoint restores it, passes through.
         """
         mat = getattr(self.params, "materialize", None)
-        return mat() if mat is not None else self.params
+        if mat is not None:
+            return mat()
+        if any(isinstance(v, jax.Array)
+               for v in jax.tree_util.tree_leaves(self.params)):
+            return pull_to_host(self.params, obs)
+        return self.params
+
+
+def pull_to_host(params, obs=NOOP):
+    """``params`` copied to the host under ``trainer.pull``, adding their
+    bytes (array metadata, read before the copy) to ``trainer.d2h_bytes``
+    and to the span."""
+    nbytes = (sum(int(v.nbytes) for v in jax.tree_util.tree_leaves(params))
+              if obs.enabled else 0)
+    with obs.wall_span("trainer.pull", d2h_bytes=nbytes):
+        host = jax.device_get(params)
+    obs.counter_add("trainer.d2h_bytes", nbytes)
+    return host
 
 
 def local_train(

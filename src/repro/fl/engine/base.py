@@ -129,10 +129,11 @@ class LocalTrainer(Component):
     Reads the global view through ``aggregator.client_params(state, ...)``
     and the round index from ``state.round`` (the per-client data/rng
     streams are keyed ``(seed, round, client)``).  Returned
-    ``ClientResult.params`` trees are host-resident (numpy): the trained
-    params are pulled to the host, and the collective aggregation
-    backend then uploads each client's blocks and scatters them into the
-    dense zero-padded contributions + masks on the device.
+    ``ClientResult.params`` trees are device-resident: the collective
+    aggregation backend scatters each client's blocks into the dense
+    zero-padded contributions + masks and stacks its bases on the
+    device, with no copy through the host.  Consumers that need numpy
+    call ``ClientResult.host_params()``.
     """
 
     def train_all(self, state: ServerState,

@@ -7,18 +7,23 @@ never leave one device.  This backend makes the paper's block-wise merge
 
   1. *prep*: every client result is turned into a dense zero-padded
      contribution + mask — the contract of
-     ``repro.core.aggregation.scatter_contributions_host``.  Staleness
-     weights (semi-async) are blended on the host, in numpy, exactly as
-     the host rule does: ``w * update + (1 - w) * global``.  The scatter
-     itself runs on the device: one compiled call per client uploads its
-     blocks and ids and writes its ``scatter_contribution`` of every
-     tensor into its row of a zeroed, donated stack (Heroes; the dense,
-     HeteroFL and Flanc rules stack numpy on the host).  When the
-     mesh-sharded cohort trainer hands over *device-resident* stacks
+     ``repro.core.aggregation.scatter_contributions_host``.  The trainers
+     hand over *device-resident* params, and with no weights in play
+     the Heroes prep reads them where they lie: one compiled call per
+     client writes its ``scatter_contribution`` of every tensor, and
+     its basis, into its row of zeroed, donated stacks, so only the
+     int32 block ids travel from the host (the merge counts these
+     clients in ``merge.device_input_clients``).  Staleness weights
+     (semi-async) are blended on the host, in numpy, exactly as the host
+     rule does: ``w * update + (1 - w) * global``; the clients' trees
+     are copied to the host through ``ClientResult.host_params`` (one
+     ``device_get`` a client, under ``trainer.pull``), and the blended
+     blocks and bases are uploaded by the same per-client call.  The
+     dense, HeteroFL and Flanc rules take their inputs through
+     ``host_params`` the same way and stack numpy on the host.  When the mesh-sharded cohort trainer hands over stacks
      (:class:`CohortStack` / :class:`CohortSlice`) and no weights are in
-     play, prep stays on device instead: rows are gathered from the
-     stacks and the dense contributions come from the compiled
-     from-device scatter — no host round-trip between train and merge.
+     play, rows are gathered from the stacks and the dense contributions
+     come from the compiled from-device scatter.
   2. *merge* (device, compiled): ONE jit call per round folds the
      stacked contributions with a fixed left-to-right ``ordered_sum``
      and divides by the mask counts.  On a multi-device mesh the client
@@ -104,14 +109,26 @@ class CohortSlice:
                                       self.stack.host())
 
 
-def _host_results(results: Dict[int, Any]) -> Dict[int, Any]:
-    """Materialize device-resident params back to the numpy contract."""
+def _materialize_slices(results: Dict[int, Any]) -> Dict[int, Any]:
+    """Results with every cohort-stack view (:class:`CohortSlice`)
+    materialized to its numpy row; plain trees, on the device or the
+    host, pass through as they are."""
     out = {}
     for n, r in results.items():
         if isinstance(r.params, CohortSlice):
             r = dataclasses.replace(r, params=r.params.materialize())
         out[n] = r
     return out
+
+
+def _host_results(results: Dict[int, Any], obs=NOOP) -> Dict[int, Any]:
+    """Results with numpy params, for the preps that blend or stack on
+    the host: each through ``ClientResult.host_params(obs)``, so a
+    device tree is copied in one ``device_get`` under ``trainer.pull``
+    (counted in ``trainer.d2h_bytes``), a cohort-stack view is
+    materialized and numpy passes through."""
+    return {n: dataclasses.replace(r, params=r.host_params(obs))
+            for n, r in results.items()}
 
 
 def _device_groups(results: Dict[int, Any]):
@@ -151,6 +168,21 @@ def _rows_in_results_order(parts: List[Any], positions: List[np.ndarray],
         return cat
 
     return jax.tree_util.tree_map(leafwise, *parts)
+
+
+def _device_input_clients(results: Dict[int, Any],
+                          weights: Optional[Dict[int, float]]) -> int:
+    """Clients whose trained params the Heroes prep reads on the device,
+    with no host copy: all of them when every result is a cohort-stack
+    view, else those whose leaves are all device arrays; none when
+    weights are blended (in numpy, on the host)."""
+    if weights is not None:
+        return 0
+    if _device_groups(results) is not None:
+        return len(results)
+    return sum(all(isinstance(v, jax.Array)
+                   for v in jax.tree_util.tree_leaves(r.params))
+               for r in results.values())
 
 
 def _np_blend(update, w: float, prev):
@@ -200,26 +232,31 @@ def _fact_1d(stacked):
 
 @functools.partial(jax.jit, static_argnames="layout")
 def _zero_contributions(layout):
-    """Zeroed ``{name: {dense, mask}}`` stacks for ``layout``, a tuple of
-    ``(name, (k_pad, num_blocks, *block_shape), dtype)``."""
-    return {name: {"dense": jnp.zeros(shape, dtype),
+    """Zeroed ``{name: {bases, dense, mask}}`` stacks for ``layout``, a
+    tuple of ``(name, (k_pad, num_blocks, *block_shape), dtype,
+    (k_pad, *basis_shape), basis_dtype)``."""
+    return {name: {"bases": jnp.zeros(bshape, bdtype),
+                   "dense": jnp.zeros(shape, dtype),
                    "mask": jnp.zeros(shape[:2], jnp.float32)}
-            for name, shape, dtype in layout}
+            for name, shape, dtype, bshape, bdtype in layout}
 
 
 @functools.partial(jax.jit, donate_argnums=0)
-def _scatter_client(contrib, row, blocks, ids):
-    """One client's Eq. 5 contributions, every tensor in one call:
+def _scatter_client(contrib, row, bases, blocks, ids):
+    """One client's merge inputs, every tensor in one call: its basis and
     ``scatter_contribution(blocks[name], ids[name])`` written in place
-    into row ``row`` of the donated stacks ``contrib``.  Its shapes
-    depend only on the client's width, so a round builds at most one
-    program per width."""
+    into row ``row`` of the donated stacks ``contrib``.  The client's
+    factors come as they are (device arrays, or numpy uploaded with the
+    call).  Its shapes depend only on the client's width, so a round
+    builds at most one program per width."""
     out = {}
     for name, t in contrib.items():
         dense, mask = aggregation.scatter_contribution(
             blocks[name].astype(t["dense"].dtype), ids[name],
             t["dense"].shape[1])
         out[name] = {
+            "bases": jax.lax.dynamic_update_index_in_dim(
+                t["bases"], bases[name].astype(t["bases"].dtype), row, 0),
             "dense": jax.lax.dynamic_update_index_in_dim(
                 t["dense"], dense, row, 0),
             "mask": jax.lax.dynamic_update_index_in_dim(
@@ -277,8 +314,10 @@ class CollectiveMerger:
         # *latency* is spanned at the loop level ("aggregate.merge"),
         # the merger spans its two stages inside it ("merge.prep",
         # "merge.compiled") and counts per-rule compiled-merge calls, the
-        # host bytes a merge ships ("merge.h2d_bytes") and the clients
-        # scattered on the device ("merge.device_scatter_clients")
+        # host bytes a merge ships ("merge.h2d_bytes"), the clients
+        # scattered on the device ("merge.device_scatter_clients") and
+        # those whose factors it read on the device
+        # ("merge.device_input_clients")
         self.obs = NOOP
 
     def _count(self, rule: str) -> None:
@@ -517,8 +556,12 @@ class CollectiveMerger:
         self._count("factorized")
         k = len(results)
         k_pad = flsh.pad_cohort(k, self.mesh)
+        on_device = (_device_input_clients(results, weights)
+                     if self.obs.enabled else 0)
         self.obs.counter_add("merge.device_scatter_clients", k)
-        with self.obs.wall_span("merge.prep", clients=k, device_scatter=k):
+        self.obs.counter_add("merge.device_input_clients", on_device)
+        with self.obs.wall_span("merge.prep", clients=k, device_scatter=k,
+                                device_input=on_device):
             stacked, shard_names, uploaded = self._stack_factorized(
                 prev_params, specs, results, assigns, weights, k_pad)
         return self._compiled(self._finish_fact, stacked, k, shard_names,
@@ -527,27 +570,30 @@ class CollectiveMerger:
     def _stack_factorized(self, prev_params, specs, results, assigns,
                           weights, k_pad: int):
         """``merge_factorized``'s inputs: ``(stacked, shard_names,
-        uploaded)``, ``uploaded`` the bytes of client blocks and ids the
-        prep sends to the device.  Each client's (blended) blocks go to
-        ``_scatter_client`` in results order; the bases stay a numpy
-        stack."""
+        uploaded)``, ``uploaded`` the bytes of client factors and ids the
+        prep sends to the device.  Each client's basis and blocks go to
+        ``_scatter_client`` in results order as they come: a
+        device-resident tree is read where it lies, numpy (a weighted
+        blend, a restored record) is uploaded with the call."""
         if weights is None:
             groups = _device_groups(results)
             if groups is not None:
                 return self._stack_factorized_device(
                     prev_params, specs, groups, k_pad, assigns) + (0,)
-        results = _host_results(results)
+        # staleness weights are blended in numpy: pull the clients' trees
+        # once each; unweighted factors are read where they lie
+        results = (_host_results(results, self.obs) if weights is not None
+                   else _materialize_slices(results))
         prev_np: Dict[str, Any] = {}
-        bases: Dict[str, List[np.ndarray]] = {name: [] for name in specs}
         contrib = None
         uploaded = 0
         for j, (n, r) in enumerate(results.items()):
             w = _weight_of(weights, n)
-            blocks, ids = {}, {}
+            bases, blocks, ids = {}, {}, {}
             for name, spec in specs.items():
                 key = "hidden_ids" if spec.mode == "square" else "anchored_ids"
                 i = np.asarray(assigns[n][key], np.int32)
-                b = np.asarray(r.params[name]["basis"])
+                b = r.params[name]["basis"]
                 c = r.params[name]["coeff"]
                 if w is not None:
                     if name not in prev_np:
@@ -556,28 +602,28 @@ class CollectiveMerger:
                             np.asarray(prev_params[name]["coeff"]))
                     b = _np_blend(b, w, prev_np[name][0])
                     c = _np_blend(c, w, prev_np[name][1][i])
-                bases[name].append(b)
+                bases[name] = b
                 blocks[name] = c
                 ids[name] = i
             if contrib is None:
                 contrib = _zero_contributions(tuple(
                     (name, (k_pad, prev_params[name]["coeff"].shape[0])
-                     + tuple(blk.shape[1:]), np.dtype(blk.dtype))
+                     + tuple(blk.shape[1:]), np.dtype(blk.dtype),
+                     (k_pad,) + tuple(bases[name].shape),
+                     np.dtype(bases[name].dtype))
                     for name, blk in blocks.items()))
-            contrib = _scatter_client(contrib, np.int32(j), blocks, ids)
-            uploaded += sum(v.nbytes for v in (*blocks.values(),
+            contrib = _scatter_client(contrib, np.int32(j), bases, blocks,
+                                      ids)
+            uploaded += sum(v.nbytes for v in (*bases.values(),
+                                               *blocks.values(),
                                                *ids.values())
                             if isinstance(v, np.ndarray))
         if self.mesh is not None:
             contrib = jax.device_put(contrib, jax.sharding.NamedSharding(
                 self.mesh, flsh.contribution_spec()))
-        stacked = {
-            name: {"bases": _pad_rows(np.stack(bases[name]), k_pad),
-                   "dense": contrib[name]["dense"],
-                   "mask": contrib[name]["mask"],
-                   "prev": prev_params[name]["coeff"]}
-            for name in specs
-        }
+        stacked = {name: dict(contrib[name],
+                              prev=prev_params[name]["coeff"])
+                   for name in specs}
         shard_names: FrozenSet[str] = frozenset()
         if self.shard_blocks:
             shard_names = frozenset(
@@ -600,7 +646,7 @@ class CollectiveMerger:
             groups = _device_groups(results)
             if groups is not None:
                 return self._device_stacked(groups, k_pad)
-        results = _host_results(results)
+        results = _host_results(results, self.obs)
         prev_np = None
         trees = []
         for n, r in results.items():
@@ -624,7 +670,7 @@ class CollectiveMerger:
 
     def _stack_masked(self, prev_params, results, weights):
         """``merge_masked_dense``'s zero-padded regions and counts."""
-        results = _host_results(results)
+        results = _host_results(results, self.obs)
         k_pad = flsh.pad_cohort(len(results), self.mesh)
         stacked = {}
         for name, full in prev_params.items():
@@ -672,7 +718,7 @@ class CollectiveMerger:
         """``merge_flanc``'s inputs: per-width coefficient stacks, or on a
         mesh one width-P zero-padded coefficient and a one-hot width row
         per client."""
-        results = _host_results(results)
+        results = _host_results(results, self.obs)
         k = len(results)
         names = list(basis)
         max_width = max(coeffs)

@@ -141,8 +141,10 @@ class SemiAsyncRoundLoop(RoundLoop):
     computed eagerly at dispatch against the then-current global state —
     exactly what a straggler's update would contain when it finally
     lands — and merged with weight ``staleness_decay ** staleness``.
-    Dispatch records live in ``state.in_flight`` (host-resident numpy
-    param trees), so an event boundary checkpoints stragglers and all.
+    Dispatch records live in ``state.in_flight``; a record still in
+    flight after an event holds its params as numpy
+    (``ClientResult.host_params``), so an event boundary checkpoints
+    stragglers and all.
     """
 
     def __init__(self, k: Optional[int] = None,
@@ -238,14 +240,14 @@ class SemiAsyncRoundLoop(RoundLoop):
                            stale=stale):
             state = eng.aggregator.aggregate(state, results, assigns,
                                              weights=weights)
-        # stragglers must not pin device-resident cohort stacks (and
-        # their host caches) across events: degrade their results to the
-        # plain numpy contract now, so each stack dies with its event —
+        # stragglers must not pin device memory (trained params, cohort
+        # stacks and their host caches) across events: degrade their
+        # results to numpy now, so device results die with their event —
         # which also keeps in-flight records checkpointable as-is
         remaining = tuple(
             dataclasses.replace(
                 t, result=dataclasses.replace(
-                    t.result, params=t.result.host_params()))
+                    t.result, params=t.result.host_params(obs)))
             for t in remaining)
 
         makespan = t_k - state.wall  # time since the previous aggregation

@@ -30,16 +30,18 @@ Every backend runs the one client step of :func:`repro.fl.client.
 client_step` (loss, gradient, SGD/proximal update, estimates), so a
 change to the step is made once.
 
-Result-params contract: backends return *host-resident* (numpy) param
-trees — the collective aggregation backend (repro.fl.engine.collective)
-uploads each client's blocks and scatters them into the dense
-zero-padded contributions on the device.  The one exception is the
-mesh-sharded cohort path feeding the collective backend: there the
-trained stack stays *device-resident* on the cohort axis
+Result-params contract: ``ClientResult.params`` may stay on the device,
+and consumers that need numpy call ``ClientResult.host_params()``.  The
+sequential and proximal trainers return the pytree the client's last
+step produced, and the collective aggregation backend
+(repro.fl.engine.collective) scatters and stacks it on the device with
+no copy to the host and back.  The mesh-sharded cohort path feeding the
+collective backend keeps its trained stack on the cohort axis
 (``ClientResult.params`` is a lazy
-:class:`~repro.fl.engine.collective.CohortSlice``) and the merge
-consumes it without a gather/rescatter; ``ClientResult.host_params()``
-recovers the numpy tree everywhere else.
+:class:`~repro.fl.engine.collective.CohortSlice``), which the merge
+consumes without a gather/rescatter.  The single-device cohort trainer
+pulls its stack to the host once (one transfer instead of one per
+client) and returns numpy rows.
 """
 
 from __future__ import annotations
@@ -56,7 +58,7 @@ from jax.sharding import NamedSharding
 from repro.core.calibration import for_dispatch
 from repro.data.streaming import stack_client_shards
 from repro.fl import client as client_lib
-from repro.fl.client import ClientResult
+from repro.fl.client import ClientResult, pull_to_host
 from repro.fl.engine.base import Assignment, LocalTrainer
 from repro.fl.engine.collective import CohortSlice, CohortStack
 from repro.fl.models import FLModelDef
@@ -83,24 +85,12 @@ def _count_recompiles(obs, fn, before: Optional[int], **labels) -> None:
         obs.counter_add("trainer.jit_recompiles", after - before, **labels)
 
 
-def _pull(obs, params):
-    """Trained params to the host under ``trainer.pull``, adding their
-    bytes (array metadata, read before the copy) to
-    ``trainer.d2h_bytes`` and to the span."""
-    nbytes = (sum(int(v.nbytes) for v in jax.tree_util.tree_leaves(params))
-              if obs.enabled else 0)
-    with obs.wall_span("trainer.pull", d2h_bytes=nbytes):
-        host = jax.device_get(params)
-    obs.counter_add("trainer.d2h_bytes", nbytes)
-    return host
-
-
 class SequentialTrainer(LocalTrainer):
     """One ``local_train`` call per client (legacy-equivalent backend).
 
-    Result params are pulled to the host (numpy) — the contract shared
-    with :class:`CohortTrainer` — and the collective merge uploads each
-    client's blocks and scatters them on the device.
+    Result params stay on the device, as the client's last step left
+    them: the collective merge scatters and stacks them there, and
+    ``ClientResult.host_params()`` copies them for a numpy consumer.
     """
 
     name = "sequential"  # the ``trainer`` label of trainer.jit_recompiles
@@ -150,7 +140,7 @@ class SequentialTrainer(LocalTrainer):
             if obs.enabled:
                 _count_recompiles(obs, program, before, trainer=self.name,
                                   width=int(a["width"]))
-            out[n] = ClientResult(_pull(obs, res.params), res.estimates,
+            out[n] = ClientResult(res.params, res.estimates,
                                   res.loss_before, res.loss_after)
         return out
 
@@ -456,7 +446,7 @@ class CohortTrainer(LocalTrainer):
                 out[n] = ClientResult(CohortSlice(stack, j), est,
                                       float(loss_b[j]), float(loss_a[j]))
             return out
-        final = _pull(obs, final)  # one transfer; slice per client below
+        final = pull_to_host(final, obs)  # one transfer; slice per client
         for j, n in enumerate(ns):
             params = jax.tree_util.tree_map(lambda v, j=j: v[j], final)
             est = {k: float(v[j]) for k, v in ests.items()} if ests else {}

@@ -34,7 +34,11 @@ SPANS: Dict[str, str] = {
         "program, read back once (attribute compiled=1, counter "
         "trainer.estimate_compiled_clients)",
     "trainer.pull":
-        "trained params copied to the host (counter trainer.d2h_bytes)",
+        "trained params copied to the host, only where a consumer asks "
+        "for numpy (ClientResult.host_params: a merge rule that blends "
+        "or stacks on the host, a semi-async straggler, a checkpoint) "
+        "and by the single-device cohort trainer (counter "
+        "trainer.d2h_bytes)",
     "trainer.host_stage":
         "cohort trainer: host batch staging of one width group",
     "trainer.device_step":
@@ -43,7 +47,8 @@ SPANS: Dict[str, str] = {
         "the server merge of one round (aggregator.aggregate)",
     "merge.prep":
         "collective merge: contributions blended, scattered and stacked "
-        "(counter merge.device_scatter_clients)",
+        "(counters merge.device_scatter_clients and, for clients whose "
+        "factors it read on the device, merge.device_input_clients)",
     "merge.compiled":
         "collective merge: the compiled call, with its host-to-device "
         "copies (counter merge.h2d_bytes)",

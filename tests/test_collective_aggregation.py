@@ -258,8 +258,10 @@ def _tiny_model(rng):
 
 def test_device_scatter_stacks_match_the_host_contract():
     """The merger's per-client device scatter lays out the same dense
-    contributions and masks as ``scatter_contributions_host``, in results
-    order: duplicate ids accumulate and each counts in the mask."""
+    contributions and masks as ``scatter_contributions_host``, and the
+    bases' stack, in results order: duplicate ids accumulate and each
+    counts in the mask.  Numpy factors are uploaded with the call:
+    blocks, bases and ids."""
     from repro.core import scatter_contributions_host
     from repro.fl.engine.collective import CollectiveMerger
 
@@ -278,7 +280,12 @@ def test_device_scatter_stacks_match_the_host_contract():
         np.testing.assert_array_equal(np.asarray(stacked[name]["mask"]),
                                       mask)
         assert mask[2, 2] == 2.0
+        assert isinstance(stacked[name]["bases"], jax.Array)
+        np.testing.assert_array_equal(
+            np.asarray(stacked[name]["bases"]),
+            np.stack([r.params[name]["basis"] for r in results.values()]))
     assert uploaded == sum(r.params[n]["coeff"].nbytes
+                           + r.params[n]["basis"].nbytes
                            + 4 * len(assigns[c]["hidden_ids"])
                            for c, r in results.items() for n in specs)
 

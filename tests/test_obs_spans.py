@@ -4,15 +4,17 @@ host<->device byte counters (``repro.obs.spans``).
 A one-round Heroes run with ``telemetry="memory"`` under
 ``jax.profiler.trace``: every span of the round shows up in the xplane
 host plane with the nesting the recorder's ``parent`` fields give; the
-merge's ``merge.h2d_bytes`` equals the bytes of the client blocks, ids
-and bases it uploads, its ``merge.prep`` counts every client as
-scattered on the device (``device_scatter``), the trainer's
-``trainer.d2h_bytes`` equals the bytes of the params it returned and
-every client's estimate runs as the compiled program (``compiled`` on
-``trainer.estimate``, counter ``trainer.estimate_compiled_clients``); with
-telemetry off no span is opened at all.
+merge's ``merge.h2d_bytes`` equals the bytes it uploads (the ids of
+device-resident results; blocks, bases and ids of numpy ones), its
+``merge.prep`` counts every client as scattered on the device
+(``device_scatter``), ``trainer.d2h_bytes`` equals the bytes of the
+params ``ClientResult.host_params`` copied and every client's estimate
+runs as the compiled program (``compiled`` on ``trainer.estimate``,
+counter ``trainer.estimate_compiled_clients``); with telemetry off no
+span is opened at all.
 """
 
+import dataclasses
 import re
 from pathlib import Path
 
@@ -29,10 +31,11 @@ from repro.obs.report import render_report
 
 SRC = Path(__file__).resolve().parents[1] / "src"
 # the spans one synchronous Heroes round opens (sequential trainer,
-# collective merge, round 1 evaluates)
+# collective merge, round 1 evaluates); the trained params stay on the
+# device, so no ``trainer.pull``
 ROUND_SPANS = {"round.assign", "round.evaluate", "trainer.client_params",
                "trainer.local_train", "trainer.sgd", "trainer.loss",
-               "trainer.estimate", "trainer.pull", "aggregate.merge",
+               "trainer.estimate", "aggregate.merge",
                "merge.prep", "merge.compiled"}
 NESTED = {"merge.prep": "aggregate.merge", "merge.compiled": "aggregate.merge",
           "trainer.sgd": "trainer.local_train",
@@ -131,32 +134,43 @@ def test_byte_counters_match_shapes(image_setup):
                        cfg=_cfg(telemetry="memory", clients_per_round=3))
     state, assigns = eng.assignment.assign(eng.state, [0, 4, 7])
     results = eng.trainer.train_all(state, assigns)
-    pulled = sum(int(v.nbytes) for r in results.values()
-                 for v in jax.tree_util.tree_leaves(r.params))
+    trained = sum(int(v.nbytes) for r in results.values()
+                  for v in jax.tree_util.tree_leaves(r.params))
     eng.aggregator.aggregate(state, results, assigns)
     sink = eng.obs.sinks[0]
     counters = eng.obs.counters
-    assert counters["trainer.d2h_bytes"] == pulled
-    assert sum(e["attrs"]["d2h_bytes"]
-               for e in sink.spans("trainer.pull")) == pulled
-    assert counters["merge.h2d_bytes"] == _merge_upload_bytes(
-        eng, results, assigns)
+    assert "trainer.d2h_bytes" not in counters
+    assert sink.spans("trainer.pull") == []
+    ids_only = _merge_upload_bytes(eng, results, assigns)
+    assert counters["merge.h2d_bytes"] == ids_only
     (compiled,) = sink.spans("merge.compiled")
-    assert compiled["attrs"]["h2d_bytes"] == counters["merge.h2d_bytes"]
+    assert compiled["attrs"]["h2d_bytes"] == ids_only
+
+    host = {n: dataclasses.replace(r, params=r.host_params(eng.obs))
+            for n, r in results.items()}
+    assert counters["trainer.d2h_bytes"] == trained
+    assert sum(e["attrs"]["d2h_bytes"]
+               for e in sink.spans("trainer.pull")) == trained
+    eng.aggregator.aggregate(state, host, assigns)
+    uploaded = _merge_upload_bytes(eng, host, assigns)
+    assert uploaded == ids_only + trained
+    assert counters["merge.h2d_bytes"] == ids_only + uploaded
+    assert sink.spans("merge.compiled")[1]["attrs"]["h2d_bytes"] == uploaded
     eng.close()
 
 
 def _merge_upload_bytes(eng, results, assigns):
-    """What the Heroes merge sends to the device: each client's trained
-    coefficient blocks and its int32 block ids, per tensor, plus the
-    stacked bases; the dense contributions are made on the device."""
+    """What the Heroes merge sends to the device: each client's int32
+    block ids per tensor, and its numpy coefficient blocks and basis; a
+    device-resident factor is read where it lies, and the dense
+    contributions and the bases' stack are made on the device."""
     total = 0
     for n, r in results.items():
         for name, spec in eng.model.specs.items():
             key = "hidden_ids" if spec.mode == "square" else "anchored_ids"
-            total += r.params[name]["coeff"].nbytes
             total += 4 * len(assigns[n][key])
-            total += r.params[name]["basis"].nbytes
+            total += sum(v.nbytes for v in r.params[name].values()
+                         if isinstance(v, np.ndarray))
     return total
 
 
